@@ -361,9 +361,19 @@ def run_mesh_sweep(shard_counts: List[int]) -> List[Dict]:
     devices* (XLA_FLAGS must be set before jax imports, so the sweep
     cannot run in this process).  Each subprocess runs
     ``--mesh-inner N`` and prints its :func:`simulate_mesh` record as
-    the last stdout line."""
+    the last stdout line.
+
+    The children run on forced CPU devices and report SigDLA model
+    cycles, never a device measurement; on a TPU host that would pass
+    CPU numbers off as the mesh result (and a child could not reach the
+    chip this process holds), so the sweep refuses to run there."""
     import subprocess
 
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"the --mesh sweep runs on forced CPU host devices; this host's "
+            f"backend is {jax.default_backend()!r}, whose mesh it cannot "
+            f"measure — run chip_smoke.py --mesh 4 on a four-chip host")
     root = os.path.join(os.path.dirname(__file__), "..")
     rows = []
     for n in shard_counts:
@@ -545,4 +555,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -3,13 +3,10 @@
 A :class:`ShufflePlan` is the compiled artifact of the programmable shuffling
 fabric: a static gather-index map plus constant padding.  On the ASIC the
 plan is an instruction stream driving 16 nibble-granular shuffle units; on
-TPU the same plan is applied either
-
-  * as a fused XLA gather/select immediately ahead of the consuming matmul
-    (:func:`apply_plan`), or
-  * inside a Pallas kernel in VMEM (kernels/shuffle_gemm), keeping the
-    HBM->VMEM stream regular exactly like the paper keeps the SRAM->array
-    stream lock-step.
+TPU the same plan is applied as an XLA gather/select immediately ahead of
+the consuming matmul — :func:`apply_plan` on the reference path, or the
+gather that writes the operand layout of the shuffle-GEMM Pallas kernels
+(kernels/shuffle_gemm).
 
 Equivalence of this fast path with the instruction-level semantics
 (`shuffle_ir` + `shuffle_compiler`) is a tested invariant (DESIGN.md §7.1).

@@ -32,11 +32,10 @@ def _kernel(a_ref, w_ref, o_ref, *, pa: int, pw: int):
 
     acc = jnp.zeros(o_ref.shape, jnp.int32)
     for i in range(pa):
-        a_i = a_ref[i].astype(jnp.int32)
         for j in range(pw):
-            w_j = w_ref[j].astype(jnp.int32)
+            # int8 planes straight into the MXU, accumulated in int32
             part = jax.lax.dot_general(
-                a_i, w_j, (((1,), (0,)), ((), ())),
+                a_ref[i], w_ref[j], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32)
             acc = acc + (part << (4 * (i + j)))
     o_ref[...] += acc
@@ -62,4 +61,5 @@ def bitserial_matmul_planes(a_planes: jax.Array, w_planes: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name="bitserial_mm",
     )(a_planes, w_planes)

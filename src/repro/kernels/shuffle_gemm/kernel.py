@@ -1,30 +1,36 @@
-"""Fused shuffling-fabric + GEMM kernels (paper §V).
+"""Shuffling-fabric + GEMM kernels (paper §V).
 
-The ASIC inserts the fabric between SRAM and the MAC array; the TPU
-analogue is performing the gather + constant-padding *in VMEM*, on the
-block already staged for the MXU, so HBM sees only sequential reads:
+The ASIC inserts the fabric between SRAM and the MAC array.  Here the
+fabric pass — gather, constant padding and the optional per-element
+``diag`` scale — is one XLA gather ahead of the kernel, and the Pallas
+kernel is the array pass:
 
-    out[b, r, :] = (x[b, idx[r, :]] | pad) (* scale) @ w    per row block
+    out[b, r, :] = (x[b, idx[r, :]] | pad) (* scale) @ w
 
 ``idx`` rows are the compiled ShufflePlan (PAD = -1 entries take
 ``pad_vals``); ``scale`` is the plan's optional constant per-element
-``diag`` (window taper, conjugation signs, 1/n) applied on the gathered
-stream — exactly where the fabric applies it on stream-in.  The source
-vector block is held fully in VMEM (signals are KB-scale; the paper's
-on-chip buffer holds them whole too).
+``diag`` (window taper, conjugation signs, 1/n).  Mosaic lowers no
+general gather over a VMEM block, so the gather stays in XLA; it writes
+the rows straight into the kernel's layout, at no extra pass.
 
-Two variants:
+Layout: rows sit on the 128 lanes and the contraction on the sublanes.
+The gathered operand is ``(B, G, t, C)`` — group ``g``'s ``C`` rows,
+each ``t`` long — and each grid step computes one lane block
+
+    out[b, g, :, c0:c1] = w[g].T @ gathered[b, g, :, c0:c1]
+
+so every block is lane-dense whatever ``t`` and ``n_out`` are (the FFT
+butterfly has t = n_out = 4).
+
+Two entry points share the kernel:
 
   * :func:`shuffle_gemm_blocks` — one shared ``(t, n_out)`` operand for
-    every row (FIR taps, DCT matrix, mel filterbank).
-    Grid = (B, R/br): batch x row-blocks; idx/pad/w broadcast over batch.
+    every row (FIR taps, DCT matrix, mel filterbank): ``G = 1``.
   * :func:`shuffle_gemm_grouped_blocks` — a *grouped* operand
     ``(G, t, n_out)``: row ``r`` (flat layout ``(reps, G, nb)``)
     contracts against group ``(r // nb) % G``.  This is the FFT
     butterfly shape — per-twiddle-class (nb, 4) x (4, 4) matmuls — for
     arbitrary gather plans (the graph compiler's fused/folded stages).
-    Grid = (B,): one program per batch element, the whole signal block
-    plus the (G, t, n_out) operand resident in VMEM (fft_stage-style).
 """
 
 from __future__ import annotations
@@ -35,89 +41,81 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
+# VMEM bytes for one lane block of the gathered operand (it is
+# double-buffered, beside the output block); keeps every block well
+# inside the default scoped VMEM limit whatever the contraction length.
+BLOCK_BYTES = 2 << 20
+MAX_BLOCK_LANES = 2048
 
-def _gather_block(x, idx, pad_ref, scale_ref):
-    """Shared VMEM gather: idx (r, t) with PAD -> -1; optional scale."""
-    safe = jnp.maximum(idx, 0)
-    g = jnp.take(x, safe.reshape(-1), axis=0).reshape(idx.shape)
-    g = jnp.where(idx < 0, pad_ref[...].astype(g.dtype), g)
-    if scale_ref is not None:
-        g = g * scale_ref[...].astype(g.dtype)
+
+def _gather(x, idx, pad_vals, scale):
+    """The fabric pass in XLA: ``x`` (B, n_in) gathered through ``idx``
+    (any shape, PAD = -1 takes ``pad_vals``), then scaled."""
+    g = jnp.take(x, jnp.maximum(idx, 0), axis=1)
+    g = jnp.where(idx < 0, pad_vals.astype(g.dtype), g)
+    if scale is not None:
+        g = g * scale.astype(g.dtype)
     return g
 
 
-def _kernel(x_ref, idx_ref, pad_ref, w_ref, o_ref):
-    g = _gather_block(x_ref[0], idx_ref[...], pad_ref, None)
-    o_ref[0] = jax.lax.dot_general(
-        g, w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=o_ref.dtype)
+def _kernel(g_ref, w_ref, o_ref):
+    # f32 operands contract at full f32 precision, as the reference does
+    o_ref[0, 0] = jax.lax.dot_general(
+        w_ref[0], g_ref[0, 0], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def _kernel_scaled(x_ref, idx_ref, pad_ref, scale_ref, w_ref, o_ref):
-    g = _gather_block(x_ref[0], idx_ref[...], pad_ref, scale_ref)
-    o_ref[0] = jax.lax.dot_general(
-        g, w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=o_ref.dtype)
+def _block_lanes(c: int, t: int, n_out: int, itemsize: int) -> int:
+    cap = BLOCK_BYTES // (itemsize * max(t, n_out)) // LANES * LANES
+    want = -(-c // LANES) * LANES
+    return max(LANES, min(want, cap, MAX_BLOCK_LANES))
 
 
-@functools.partial(jax.jit, static_argnames=("br", "interpret"))
-def shuffle_gemm_blocks(x: jax.Array, idx: jax.Array, pad_vals: jax.Array,
-                        w: jax.Array, br: int = 256,
-                        interpret: bool = True,
-                        scale: jax.Array | None = None) -> jax.Array:
-    """x: (B, n_in); idx/pad_vals[/scale]: (R, t); w: (t, n_out) ->
-    (B, R, n_out).  R must be a multiple of ``br`` (ops.py pads)."""
-    b, n_in = x.shape
+def _grouped_gemm(x, idx, pad_vals, w, reps, groups, nb, interpret, scale):
+    """x: (B, n_in); idx/pad_vals[/scale]: (R, t), R = reps*G*nb rows in
+    (reps, G, nb) order; w: (G, t, n_out) -> (B, reps, G, nb, n_out)."""
+    b = x.shape[0]
     r, t = idx.shape
     n_out = w.shape[-1]
-    grid = (b, r // br)
-    specs = [
-        pl.BlockSpec((1, n_in), lambda bb, rr: (bb, 0)),
-        pl.BlockSpec((br, t), lambda bb, rr: (rr, 0)),
-        pl.BlockSpec((br, t), lambda bb, rr: (rr, 0)),
-    ]
-    args = [x, idx, pad_vals]
-    kernel = _kernel
-    if scale is not None:
-        specs.append(pl.BlockSpec((br, t), lambda bb, rr: (rr, 0)))
-        args.append(scale)
-        kernel = _kernel_scaled
-    specs.append(pl.BlockSpec((t, n_out), lambda bb, rr: (0, 0)))
-    args.append(w)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=specs,
-        out_specs=pl.BlockSpec((1, br, n_out), lambda bb, rr: (bb, rr, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, r, n_out), x.dtype),
+    c = reps * nb
+    bl = _block_lanes(c, t, n_out, x.dtype.itemsize)
+    cp = -(-c // bl) * bl
+
+    def to_lanes(a, fill):                      # (R, t) -> (G, t, Cp)
+        a = a.reshape(reps, groups, nb, t).transpose(1, 3, 0, 2)
+        a = a.reshape(groups, t, c)
+        return jnp.pad(a, ((0, 0), (0, 0), (0, cp - c)),
+                       constant_values=fill)
+
+    g = _gather(x, to_lanes(idx, -1), to_lanes(pad_vals, 0),
+                None if scale is None else to_lanes(scale, 0))
+    y = pl.pallas_call(
+        _kernel,
+        grid=(b, groups, cp // bl),
+        in_specs=[pl.BlockSpec((1, 1, t, bl), lambda i, j, k: (i, j, 0, k)),
+                  pl.BlockSpec((1, n_out, t), lambda i, j, k: (j, 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, n_out, bl),
+                               lambda i, j, k: (i, j, 0, k)),
+        out_shape=jax.ShapeDtypeStruct((b, groups, n_out, cp), x.dtype),
         interpret=interpret,
-    )(*args)
+        name="shuffle_gemm",
+    )(g, jnp.swapaxes(w, 1, 2).astype(x.dtype))
+    y = y[..., :c].reshape(b, groups, n_out, reps, nb)
+    return y.transpose(0, 3, 1, 4, 2)
 
 
-def _grouped_kernel(x_ref, idx_ref, pad_ref, w_ref, o_ref, *,
-                    reps: int, groups: int, nb: int):
-    g = _gather_block(x_ref[0], idx_ref[...], pad_ref, None)
-    _grouped_body(g, w_ref, o_ref, reps, groups, nb)
-
-
-def _grouped_kernel_scaled(x_ref, idx_ref, pad_ref, scale_ref, w_ref,
-                           o_ref, *, reps: int, groups: int, nb: int):
-    g = _gather_block(x_ref[0], idx_ref[...], pad_ref, scale_ref)
-    _grouped_body(g, w_ref, o_ref, reps, groups, nb)
-
-
-def _grouped_body(g, w_ref, o_ref, reps, groups, nb):
-    t = g.shape[-1]
-    w = w_ref[...]                              # (G, t, n_out)
-    rows = g.reshape(reps, groups, nb, t).transpose(1, 0, 2, 3) \
-        .reshape(groups, reps * nb, t)
-    # y[j, rb, o] = sum_t rows[j, rb, t] * w[j, t, o]
-    y = jax.lax.dot_general(
-        rows, w, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=o_ref.dtype)     # (G, reps*nb, n_out)
-    n_out = w.shape[-1]
-    o_ref[0] = y.reshape(groups, reps, nb, n_out).transpose(1, 0, 2, 3) \
-        .reshape(-1)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def shuffle_gemm_blocks(x: jax.Array, idx: jax.Array, pad_vals: jax.Array,
+                        w: jax.Array, interpret: bool = True,
+                        scale: jax.Array | None = None) -> jax.Array:
+    """x: (B, n_in); idx/pad_vals[/scale]: (R, t); w: (t, n_out) ->
+    (B, R, n_out)."""
+    r = idx.shape[0]
+    y = _grouped_gemm(x, idx, pad_vals, w[None], r, 1, 1, interpret,
+                      scale)
+    return y.reshape(x.shape[0], r, w.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("reps", "groups", "nb",
@@ -131,27 +129,6 @@ def shuffle_gemm_grouped_blocks(x: jax.Array, idx: jax.Array,
     """x: (B, n_in); idx/pad_vals[/scale]: (R, t) with R = reps*G*nb in
     (reps, G, nb) row order; w: (G, t, n_out) -> (B, R * n_out) flat in
     the same row order (the einsum's natural ``...fjbo`` layout)."""
-    b, n_in = x.shape
-    r, t = idx.shape
-    n_out = w.shape[-1]
-    specs = [
-        pl.BlockSpec((1, n_in), lambda bb: (bb, 0)),
-        pl.BlockSpec((r, t), lambda bb: (0, 0)),
-        pl.BlockSpec((r, t), lambda bb: (0, 0)),
-    ]
-    args = [x, idx, pad_vals]
-    kernel = _grouped_kernel
-    if scale is not None:
-        specs.append(pl.BlockSpec((r, t), lambda bb: (0, 0)))
-        args.append(scale)
-        kernel = _grouped_kernel_scaled
-    specs.append(pl.BlockSpec(w.shape, lambda bb: (0, 0, 0)))
-    args.append(w)
-    return pl.pallas_call(
-        functools.partial(kernel, reps=reps, groups=groups, nb=nb),
-        grid=(b,),
-        in_specs=specs,
-        out_specs=pl.BlockSpec((1, r * n_out), lambda bb: (bb, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, r * n_out), x.dtype),
-        interpret=interpret,
-    )(*args)
+    y = _grouped_gemm(x, idx, pad_vals, w, reps, groups, nb, interpret,
+                      scale)
+    return y.reshape(x.shape[0], -1)

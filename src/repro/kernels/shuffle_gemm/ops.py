@@ -1,5 +1,6 @@
-"""Public wrappers: run a compiled ShufflePlan + GEMM through the fused
-Pallas kernels.  Accepts the same ShufflePlan objects as core.fabric.
+"""Public wrappers: run a compiled ShufflePlan + GEMM through the
+shuffle-GEMM Pallas kernels.  Accepts the same ShufflePlan objects as
+core.fabric.
 
 Both ops carry a custom VJP (vjp.py): the transpose of a gather∘einsum
 group is another gather∘einsum group, so reverse-mode differentiation
@@ -23,11 +24,10 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
 
 
 def shuffle_gemm(x: jax.Array, plan: ShufflePlan, w: jax.Array,
-                 rows: int, br: int = 256,
-                 interpret: Optional[bool] = None,
+                 rows: int, interpret: Optional[bool] = None,
                  diag=None) -> jax.Array:
-    """out = reshape(apply_plan(x) (* diag), (rows, t)) @ w, fused in one
-    kernel.
+    """out = reshape(apply_plan(x) (* diag), (rows, t)) @ w: an XLA
+    gather feeding one GEMM kernel.
 
     x: (..., n_in); plan.n_out == rows * t; w: (t, n_out); diag is an
     optional per-element scale of the gathered stream (a GatherStep /
@@ -37,8 +37,8 @@ def shuffle_gemm(x: jax.Array, plan: ShufflePlan, w: jax.Array,
     Differentiable in ``x`` and ``w`` via a custom VJP whose backward
     pass runs on the same kernels (see shuffle_gemm/vjp.py).
     """
-    return gemm_call(x, plan, w, rows, br,
-                     _resolve_interpret(interpret), diag)
+    return gemm_call(x, plan, w, rows, _resolve_interpret(interpret),
+                     diag)
 
 
 def shuffle_gemm_grouped(x: jax.Array, plan: ShufflePlan, w: jax.Array,

@@ -58,22 +58,14 @@ def plan_blocks(plan: ShufflePlan, diag, rows: int, dtype):
     return t, idx, pads, scale
 
 
-def blocks_call(xb: jax.Array, idx, pads, w: jax.Array, rows: int,
-                br: int, interpret: bool, scale=None) -> jax.Array:
-    """Pad the row blocks to a ``br`` multiple, run the fused kernel,
-    slice the padding back off.  ``xb``: (B, n_in) -> (B, rows, n_out)."""
-    br_ = min(br, rows)
-    rem = (-rows) % br_
-    if rem:
-        idx = np.pad(idx, ((0, rem), (0, 0)), constant_values=0)
-        pads = np.pad(pads, ((0, rem), (0, 0)))
-        if scale is not None:
-            scale = np.pad(scale, ((0, rem), (0, 0)))
-    out = shuffle_gemm_blocks(
+def blocks_call(xb: jax.Array, idx, pads, w: jax.Array,
+                interpret: bool, scale=None) -> jax.Array:
+    """Run the shared-operand kernel on host-built blocks.
+    ``xb``: (B, n_in) -> (B, rows, n_out)."""
+    return shuffle_gemm_blocks(
         xb, jnp.asarray(idx), jnp.asarray(pads, dtype=xb.dtype), w,
-        br=br_, interpret=interpret,
+        interpret=interpret,
         scale=None if scale is None else jnp.asarray(scale))
-    return out[:, :rows]
 
 
 def _identity_blocks(rows: int, t: int):
@@ -124,24 +116,24 @@ def adjoint_lowering(plan: ShufflePlan, n_in: int, diag=None):
 
 
 def _adjoint_dx(dg_flat: jax.Array, plan: ShufflePlan, n_in: int, diag,
-                br: int, interpret: bool) -> jax.Array:
+                interpret: bool) -> jax.Array:
     """Run the cached adjoint lowering on a flat cotangent:
     (B, rows * t) -> (B, n_in)."""
     aidx, apads, ascale, ones = adjoint_lowering(plan, n_in, diag)
     dx = blocks_call(dg_flat, aidx, apads,
-                     jnp.asarray(ones, dg_flat.dtype), n_in, br,
-                     interpret, scale=ascale)
+                     jnp.asarray(ones, dg_flat.dtype), interpret,
+                     scale=ascale)
     return dx[..., 0]
 
 
 def gemm_call(x: jax.Array, plan: ShufflePlan, w: jax.Array, rows: int,
-              br: int, interpret: bool, diag) -> jax.Array:
+              interpret: bool, diag) -> jax.Array:
     """:func:`repro.kernels.shuffle_gemm` body with a custom VJP.
     x: (..., n_in), w: (t, n_out) -> (..., rows, n_out)."""
     t, idx, pads, scale = plan_blocks(plan, diag, rows, x.dtype)
 
     def impl(xb, w):
-        return blocks_call(xb, idx, pads, w, rows, br, interpret, scale)
+        return blocks_call(xb, idx, pads, w, interpret, scale)
 
     def fwd(xb, w):
         return impl(xb, w), (xb, w)
@@ -154,11 +146,11 @@ def gemm_call(x: jax.Array, plan: ShufflePlan, w: jax.Array, rows: int,
         # gather (same kernel, operand transposed)
         iidx, ipads = _identity_blocks(rows, n_out)
         dg = blocks_call(dy.reshape(b, rows * n_out), iidx, ipads,
-                         jnp.transpose(w), rows, br, interpret)
+                         jnp.transpose(w), interpret)
         # d_x — scatter-as-gather of the inverse index map (+ diag),
         # reduced on the array
         dx = _adjoint_dx(dg.reshape(b, rows * t), plan, n_in, diag,
-                         br, interpret)
+                         interpret)
         # d_w — gathered activations against the cotangent (dense GEMM)
         g = apply_plan(xb, plan)
         if scale is not None:
@@ -204,7 +196,7 @@ def grouped_call(x: jax.Array, plan: ShufflePlan, w: jax.Array,
             dy, jnp.asarray(iidx), jnp.asarray(ipads, dy.dtype),
             jnp.transpose(w, (0, 2, 1)), reps=reps, groups=groups,
             nb=nb, interpret=interpret)
-        dx = _adjoint_dx(dg_flat, plan, n_in, diag, 256, interpret)
+        dx = _adjoint_dx(dg_flat, plan, n_in, diag, interpret)
         g = apply_plan(xb, plan)
         if scale is not None:
             g = g * jnp.asarray(scale.reshape(-1), g.dtype)

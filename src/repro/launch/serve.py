@@ -3,9 +3,9 @@
     PYTHONPATH=src python -m repro.launch.serve --arch gemma2-2b \
         --reduced --requests 6 --max-new 16 [--quant-bits 8]
 
-Full configs are meant for the TPU pod (the decode_32k / long_500k cells
-of the dry-run prove they lower+compile); --reduced serves the same
-architecture family at CPU scale.
+--reduced (the default) serves the architecture family cut to CPU
+scale; --no-reduced serves it at its published widths, which needs an
+accelerator (starcoder2-3b: about 6.4 GB of bf16 weights).
 """
 
 from __future__ import annotations
@@ -19,7 +19,10 @@ import jax
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma2-2b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the architecture cut to CPU scale; "
+                         "--no-reduced serves its published widths")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)

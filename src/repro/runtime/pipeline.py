@@ -22,7 +22,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -76,11 +75,11 @@ def spmd_pipeline(fn: Callable, stage_params, x, *, mesh, axis_name: str,
 
     spec_params = jax.tree_util.tree_map(
         lambda _: P(axis_name), stage_params)
-    out = shard_map(
+    out = jax.shard_map(
         stage_body, mesh=mesh,
         in_specs=(spec_params, P(*([None] * x.ndim))),
         out_specs=P(axis_name, *([None] * (x.ndim - 1))),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x[None])
     # output lives on the last stage's slot; collapse the stage dim
     return out[-1]

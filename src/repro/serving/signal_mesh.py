@@ -13,7 +13,9 @@ Two pieces, deliberately separable:
     **logical shard count** with zero rows — every compiled graph is
     row-independent (batched einsums over per-row suffix axes), so pad
     rows compute garbage that is simply never read back, and the
-    real rows' values are bit-identical to the unsharded execution.
+    real rows' values equal the unsharded execution's up to float32
+    rounding (per-device programs see fewer rows, and XLA does not
+    promise one reduction order across shapes).
     ``n_shards`` may exceed the physical device count (shards then
     co-locate, wrapping round-robin over the devices) — that keeps the
     routing / occupancy / affinity logic testable in a single-device
@@ -26,9 +28,9 @@ Two pieces, deliberately separable:
     the perf model (:func:`repro.core.perf_model.device_step_costs`),
     and liveness flags so a dropped device stops receiving work.
 
-Neither piece touches request payloads; bit-identity of sharded
-serving is the service's contract, proven in
-tests/test_signal_mesh_faults.py on a forced 8-device host mesh.
+Neither piece touches request payloads; sharded serving against the
+unsharded service is tested in tests/test_signal_mesh_faults.py on
+forced 4- and 8-device host meshes.
 """
 
 from __future__ import annotations
@@ -106,6 +108,32 @@ class SignalMesh:
         """Place a (rows-padded) batch row-sharded over the mesh."""
         arr = jnp.asarray(arr)
         return jax.device_put(arr, self.row_sharding(arr.shape))
+
+    def row_parallel(self, fn, n_rows: int):
+        """Jitted ``fn(*rows, params)`` for row-sharded batches: the
+        ``n_rows`` leading arguments and every output split by row over
+        the mesh's batch axes, ``params`` replicated.  It runs as a
+        ``shard_map`` — each device computes its own rows — because XLA
+        cannot partition a Pallas (Mosaic) kernel, and the row math is
+        independent, so per-shard execution is exact.  Batches whose
+        rows do not split evenly (shards wrapped onto fewer devices) or a
+        one-device mesh take the plain jit on the replicated batch."""
+        from jax.sharding import PartitionSpec as P
+        from ..models.sharding import batch_axes, mesh_axes_of
+        sizes = mesh_axes_of(self.mesh)
+        axes = batch_axes(sizes)
+        n_dev = math.prod(sizes[a] for a in axes)
+        whole = jax.jit(fn)
+        if n_dev < 2:
+            return whole
+        spec = P(axes if len(axes) > 1 else axes[0])
+        split = jax.jit(jax.shard_map(
+            fn, mesh=self.mesh, in_specs=(spec,) * n_rows + (P(),),
+            out_specs=spec, check_vma=False))
+
+        def call(*args):
+            return (split if args[0].shape[0] % n_dev == 0 else whole)(*args)
+        return call
 
     # -- streaming-session affinity ----------------------------------------
     def device_for(self, shard_index: int):

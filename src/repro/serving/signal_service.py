@@ -206,20 +206,23 @@ class SignalService:
     ``backend`` selects the execution backend for every compiled
     program the service runs — bucket compiles AND streaming-session
     cores (:mod:`repro.signal.backends`: ``"reference"`` jnp
-    interpretation, ``"pallas"`` fused fabric+array kernels; same
+    interpretation, ``"pallas"`` shuffle-GEMM array kernels; same
     switch as ``SignalGraph.compile`` / ``StreamingRunner``).
 
     ``mesh`` shards the service data-parallel over a device mesh
     (:class:`~repro.serving.signal_mesh.SignalMesh`; an int shard
     count or a jax ``Mesh`` coerce).  Bucket batches pad their row
-    count to a shard multiple and execute row-sharded via
-    ``NamedSharding``; streaming sessions get device affinity (a
-    least-loaded shard assigned at ``open_stream``, where their
-    carried :class:`StreamState` then stays put across ticks); a
+    count to a shard multiple, are placed row-sharded via
+    ``NamedSharding`` and execute as a row-split ``shard_map``
+    (:meth:`SignalMesh.row_parallel` — XLA cannot partition the
+    ``pallas`` backend's kernels); streaming sessions get device
+    affinity (a least-loaded shard assigned at ``open_stream``, where
+    their carried :class:`StreamState` then stays put across ticks); a
     :class:`DeviceRouter` keeps the per-device cycle ledger the
-    ``CoScheduler`` reports.  Outputs are bit-identical to the
-    unsharded path — pad rows are zero rows of row-independent math,
-    trimmed before anything reads them.  ``mesh=None`` (default) is
+    ``CoScheduler`` reports.  Outputs equal the unsharded path's up to
+    float32 rounding — pad rows are zero rows of row-independent math,
+    trimmed before anything reads them, and the per-device programs
+    see fewer rows.  ``mesh=None`` (default) is
     the original single-device service, byte for byte.
     """
 
@@ -679,9 +682,8 @@ class SignalService:
             out = self._run_masked(key, compiled, reg, batch, lens,
                                    classes[0][0])
         else:
-            if key not in self._jitted:
-                self._jitted[key] = compiled.jit()
-            out = _to_host(self._jitted[key](batch, classes[0][0]))
+            out = _to_host(self._plain_call(key, compiled)(batch,
+                                                           classes[0][0]))
         self.stats["bucketed" if masked else "exact"] += 1
 
         self.stats["batches"] += 1
@@ -773,6 +775,14 @@ class SignalService:
         return {name: trim(np.asarray(out[name])[i], name)
                 for name in compiled.outputs}
 
+    def _plain_call(self, key, compiled):
+        """The bucket's unmasked jitted entry point (row-parallel on a
+        mesh), compiled once per (graph, bucket)."""
+        if key not in self._jitted:
+            self._jitted[key] = compiled.jit() if self.mesh is None \
+                else self.mesh.row_parallel(compiled.__call__, 1)
+        return self._jitted[key]
+
     def _run_masked(self, key, compiled, reg, batch, lens,
                     params) -> np.ndarray:
         """Masked/padded execution: valid-frame counts per row are traced
@@ -781,11 +791,14 @@ class SignalService:
         if struct.framer is None:
             # pure sample chain: causal stages never read past a row's
             # valid prefix, so padding needs no masking — only trimming.
-            if key not in self._jitted:
-                self._jitted[key] = compiled.jit()
-            return _to_host(self._jitted[key](batch, params))
+            return _to_host(self._plain_call(key, compiled)(batch, params))
         if key not in self._masked_jitted:
-            self._masked_jitted[key] = compiled.masked_jit()
+            if self.mesh is None:
+                self._masked_jitted[key] = compiled.masked_jit()
+            else:
+                def masked(x, vf, p):
+                    return compiled(x, p, valid_frames=vf)
+                self._masked_jitted[key] = self.mesh.row_parallel(masked, 2)
         # sharded batches carry zero pad rows past the wave: 0 valid
         # frames masks every frame of a pad row (an all-zero result
         # nothing reads back).

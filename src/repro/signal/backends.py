@@ -14,13 +14,15 @@ Two backends ship:
     (:func:`repro.core.exec_ir.run_steps_reference`): byte-for-byte the
     pre-backend execution path, differentiable, the parity oracle.
   * ``pallas`` — lowers each ``gather ∘ einsum (∘ post-shuffle)`` group
-    onto the fused fabric+array kernels, the software analogue of the
+    onto the shuffle-GEMM array kernels, the software analogue of the
     paper's fabric feeding the computing array:
 
       - row-uniform einsums (FIR taps, DCT, mel, DWT banks) run through
         :func:`repro.kernels.shuffle_gemm` — the standalone gather ahead
         of the einsum AND the v2-folded ``pre``/``pre_diag`` stream
-        shuffle are absorbed into the kernel's in-VMEM gather;
+        shuffle compose into ONE XLA gather that writes the kernel's
+        operand layout (Mosaic lowers no general in-VMEM gather, so the
+        fabric pass is reported as emulated);
       - grouped einsums (the FFT butterfly: per-twiddle-class matmuls)
         run through :func:`repro.kernels.shuffle_gemm_grouped`;
       - steps named by a :class:`PrecisionPolicy` are *int-routed*: the
@@ -93,8 +95,9 @@ class StepRoute:
     """Where one lowered step executes under a backend.  ``route`` is one
     of ``fused_gemm`` / ``fused_grouped`` / ``int_bitserial`` (array
     kernels), ``jnp`` (emulated), ``host`` (lambda glue);
-    ``absorbed_gathers`` counts standalone fabric passes folded into the
-    kernel's in-VMEM gather."""
+    ``absorbed_gathers`` counts standalone fabric passes a kernel
+    performs itself — none today: every kernel's gather is an XLA gather
+    ahead of it, reported as its own ``jnp`` gather route."""
     stage: str
     step: str
     kind: str                   # 'gather' | 'einsum' | 'lambda'
@@ -298,7 +301,7 @@ def group_plan(e: EinsumStep, gather: Optional[GatherStep]
     """Classify a ``(gather?) ∘ einsum`` pair as one fused kernel group.
 
     Returns ``(shape, plan, diag)`` — the canonical GEMM shape and the
-    single composed fabric plan the kernel gathers in VMEM — or ``None``
+    single composed fabric plan gathered ahead of the kernel — or ``None``
     when the spec is outside the kernel family or the plan's output
     length disagrees with the einsum's flat input.  This is the single
     source of truth for *which* step groups lower onto the array: the
@@ -310,7 +313,7 @@ def group_plan(e: EinsumStep, gather: Optional[GatherStep]
         return None
     n_in_flat = _prod(e.reshape_in)
     # compose the standalone gather and the v2-folded stream-in shuffle
-    # into ONE plan the kernel gathers in VMEM.
+    # into ONE plan, gathered once ahead of the kernel.
     if gather is not None:
         plan, diag = compose_into_einsum(gather.plan, gather.diag,
                                          e.pre, e.pre_diag)
@@ -449,7 +452,7 @@ class ReferenceBackend(ExecBackend):
 
 
 class PallasBackend(ExecBackend):
-    """Lower gather∘einsum(∘post) groups onto the fused Pallas kernels.
+    """Lower gather∘einsum(∘post) groups onto the Pallas array kernels.
 
     ``interpret=None`` resolves via
     :func:`repro.kernels.interpret_default` at bind time (interpret on
@@ -494,16 +497,12 @@ class PallasBackend(ExecBackend):
                 if unit is not None:
                     fn, route = unit
                     units.append(fn)
-                    if route.route == "int_bitserial":
-                        # the int route gathers via apply_plan (the
-                        # bitserial kernel has no fused gather): the
-                        # absorbed pass is emulated, not fused.
-                        routes.append(StepRoute(stage.name, s.name,
-                                                "gather", "jnp"))
-                        routes.append(route)
-                    else:
-                        routes.append(dataclasses.replace(
-                            route, absorbed_gathers=1))
+                    # the group's gather runs as an XLA gather ahead of
+                    # the array kernel (no kernel gathers in VMEM): the
+                    # fabric pass is emulated, not fused.
+                    routes.append(StepRoute(stage.name, s.name,
+                                            "gather", "jnp"))
+                    routes.append(route)
                     i += 2
                     continue
             if isinstance(s, EinsumStep):

@@ -1413,14 +1413,6 @@ class CompiledSignalGraph:
             return self.__call__(x, params, valid_frames=valid_frames)
         return jax.jit(call)
 
-    def sharded_jit(self, mesh, batch_axis: str = "data"):
-        """Batch-sharded entry point: input (and output) sharded along the
-        leading batch axis of ``mesh``; params replicated."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        xs = NamedSharding(mesh, P(batch_axis))
-        return jax.jit(self.__call__, in_shardings=(xs, None),
-                       out_shardings=xs)
-
     # -- accounting (consumed by perf_model.signal_graph_report) ------------
     def gather_steps(self) -> List[GatherStep]:
         """The standalone fabric passes (buffer -> fabric -> buffer)."""

@@ -33,7 +33,8 @@ def test_sharded_train_step_matches_single_device():
         p1, o1, m1 = jax.jit(step)(params, opt, batch)
 
         # sharded
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ("data", "model"))
         axes = SH.mesh_axes_of(mesh)
         shard = lambda t: jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s), t,
@@ -55,9 +56,10 @@ def test_sharded_train_step_matches_single_device():
 def test_spmd_pipeline_matches_sequential():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np, json
+        from repro.launch.mesh import auto_mesh
         from repro.runtime.pipeline import spmd_pipeline
 
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = auto_mesh((4,), ("stage",))
         n_stages, n_mb, mb, d = 4, 8, 2, 16
         ks = jax.random.split(jax.random.PRNGKey(0), n_stages)
         stage_params = {"w": jax.vmap(
@@ -85,14 +87,15 @@ def test_elastic_checkpoint_restore_across_meshes(tmp_path):
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import Checkpointer
+        from repro.launch.mesh import auto_mesh
 
         tree = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
-        m1 = jax.make_mesh((2, 2), ("data", "model"))
+        m1 = auto_mesh((2, 2), ("data", "model"))
         t1 = jax.device_put(tree, NamedSharding(m1, P("data", "model")))
         ck = Checkpointer({str(tmp_path)!r})
         ck.save(3, t1, blocking=True)
 
-        m2 = jax.make_mesh((4, 1), ("data", "model"))
+        m2 = auto_mesh((4, 1), ("data", "model"))
         sh = {{"w": NamedSharding(m2, P("data", None))}}
         step, back = ck.restore(like=tree, shardings=sh)
         ok = bool(np.array_equal(np.asarray(back["w"]),
@@ -107,20 +110,20 @@ def test_elastic_checkpoint_restore_across_meshes(tmp_path):
 def test_compressed_allreduce_shardmap():
     out = run_sub("""
         import jax, jax.numpy as jnp, numpy as np, json, functools
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import auto_mesh
         from repro.optim.compression import (allreduce_compressed,
                                              compress_int8)
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = auto_mesh((4,), ("pod",))
         x = jax.random.normal(jax.random.PRNGKey(0), (4, 128)) * 1e-3
 
         def body(xs):
             q, s = compress_int8(xs[0])
             return allreduce_compressed(q, s, "pod")[None]
 
-        got = shard_map(body, mesh=mesh, in_specs=P("pod"),
-                        out_specs=P("pod"), check_rep=False)(x)
+        got = jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
+                            out_specs=P("pod"), check_vma=False)(x)
         ref = jnp.mean(x, axis=0)
         rel = float(jnp.max(jnp.abs(got[0] - ref)) /
                     (jnp.max(jnp.abs(ref)) + 1e-12))
@@ -140,8 +143,8 @@ def test_dryrun_tiny_cell():
         # shrink the production mesh for the test
         import repro.launch.mesh as M
         M.make_production_mesh = lambda multi_pod=False: (
-            jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-            if multi_pod else jax.make_mesh((2, 2), ("data", "model")))
+            M.auto_mesh((2, 2, 2), ("pod", "data", "model"))
+            if multi_pod else M.auto_mesh((2, 2), ("data", "model")))
         cfg = get_config("gemma2-2b").reduced(
             n_layers=2, d_model=64, n_heads=4, d_ff=128, vocab=512)
         cfg = dataclasses.replace(cfg, dtype="bfloat16", microbatch=2,

@@ -342,3 +342,70 @@ def test_device_loss_mid_stream_resumes_bit_identical_on_8_devices(
     assert r["moved"], "session never re-homed off the dead shard"
     assert r["restores"] >= 1
     assert r["match"], "resumed stream is not bit-identical"
+
+
+def test_pallas_service_runs_row_parallel_on_4_devices(forced_mesh):
+    """The pallas backend on a 4-device mesh: bucket calls run as a
+    row-split shard_map (XLA cannot partition a Mosaic kernel), every
+    device computes its own rows, and the results match the unsharded
+    pallas service."""
+    out = forced_mesh("""
+        import jax, jax.numpy as jnp, numpy as np, json
+        from repro.serving import SignalService, SignalRequest, SignalMesh
+        from repro.signal import SignalGraph
+
+        def mask(p, z):
+            return jax.nn.sigmoid(jnp.abs(z) - 1.0)
+
+        g = SignalGraph("f")
+        g.fir("front", "input", taps=np.hanning(5) / 2.0)
+        g.stft("spec", "front", frame=256, hop=128)
+        g.dnn("mask", "spec", fn=mask)
+        g.mul("enh", "spec", "mask")
+        g.istft("out", "enh", hop=128)
+        g.magnitude("mag", "enh", onesided=True)
+        g.mel_filterbank("mel", "mag", sr=16_000, n_mels=8)
+        g.outputs("out", "mel")
+
+        rng = np.random.default_rng(3)
+        lens = [1024, 900, 700, 1024, 640]
+        sigs = [rng.standard_normal(n).astype(np.float32) for n in lens]
+        reqs = lambda: [SignalRequest(rid=i, graph="f", samples=s)
+                        for i, s in enumerate(sigs)]
+        one = SignalService(batch_size=8, backend="pallas")
+        one.register("f", g)
+        svc = SignalService(batch_size=8, backend="pallas",
+                            mesh=SignalMesh(4))
+        svc.register("f", g)
+        r0, r1 = one.serve(reqs()), svc.serve(reqs())
+        err = max(float(np.max(np.abs(r0[i][k] - r1[i][k])))
+                  for i in r0 for k in ("out", "mel"))
+
+        fn = svc.mesh.row_parallel(lambda x, p: x * p, 1)
+        y = fn(svc.mesh.shard(np.ones((8, 4), np.float32)), 2.0)
+        print(json.dumps({
+            "err": err,
+            "keys": sorted(r0) == sorted(r1),
+            "row_axis": y.sharding.spec[0],
+            "y_devices": len(y.sharding.device_set),
+            "busy": sum(1 for c in svc.router.occupancy()["device_cycles"]
+                        if c > 0),
+        }))
+    """, devices=4)
+    r = last_json(out)
+    assert r["keys"]
+    # per-shard programs see 2 rows where the unsharded one sees 8; XLA
+    # may order float32 reductions differently, so allow a few ULPs of
+    # outputs of order 1
+    assert r["err"] < 1e-5, r
+    assert r["row_axis"] == "data" and r["y_devices"] == 4
+    assert r["busy"] == 4
+
+
+def test_mesh_sweep_refuses_accelerator_hosts(monkeypatch):
+    """The bench's --mesh sweep times forced CPU host devices; on a TPU
+    host it must refuse rather than report CPU numbers as the mesh's."""
+    from benchmarks import signal_service_bench as bench
+    monkeypatch.setattr(bench.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="forced CPU host devices"):
+        bench.run_mesh_sweep([1])
