@@ -49,7 +49,7 @@ from .fabric import ShufflePlan, apply_plan
 __all__ = ["GatherStep", "EinsumStep", "LambdaStep", "Step",
            "StageProgram", "ExecProgram", "run_steps_reference",
            "execute_program", "mask_frames", "adjoint_gather_steps",
-           "callable_token", "INPUT"]
+           "callable_token", "step_kind", "INPUT"]
 
 INPUT = "input"     # the reserved graph-input name (SignalGraph.INPUT)
 
@@ -123,33 +123,48 @@ Step = object  # GatherStep | EinsumStep | LambdaStep
 # The reference step semantics (the pre-backend jnp interpreter, verbatim)
 # --------------------------------------------------------------------------
 
+def step_kind(step: Step) -> str:
+    """``gather``, ``einsum`` or ``lambda``: the kind of a step, as the
+    lowering routes and the compiled program's named scopes give it."""
+    if isinstance(step, GatherStep):
+        return "gather"
+    return "einsum" if isinstance(step, EinsumStep) else "lambda"
+
+
 def run_steps_reference(steps: Sequence[Step], x: jax.Array,
                         params) -> jax.Array:
     """Interpret a step list with plain ``jnp`` ops.  This IS the
     execution contract: every backend must match it (the ``reference``
     backend byte-for-byte; lowered backends to float tolerance, since a
-    fused kernel may re-associate the same multiplies)."""
+    fused kernel may re-associate the same multiplies).  Each step runs
+    under the named scope ``<kind>:<step name>``, and an einsum's
+    stream-in and stream-out permutations under ``gather:<step name>``
+    inside it, so the compiled program's ops say which step they are."""
     for s in steps:
-        if isinstance(s, GatherStep):
-            x = apply_plan(x, s.plan)
-            if s.diag is not None:
-                x = x * jnp.asarray(s.diag, dtype=x.dtype)
-        elif isinstance(s, EinsumStep):
-            if s.pre is not None:
-                x = apply_plan(x, s.pre)
-            if s.pre_diag is not None:
-                # applied even without a pre plan (identity stream-in):
-                # the lowered backends honor a bare pre_diag too, and
-                # the two must agree on every expressible program.
-                x = x * jnp.asarray(s.pre_diag, dtype=x.dtype)
-            h = x.reshape(*x.shape[:-1], *s.reshape_in)
-            op = resolve_operand(s, params)
-            y = jnp.einsum(s.spec, h, jnp.asarray(op, dtype=h.dtype))
-            x = y.reshape(*y.shape[:-s.out_rank], -1)
-            if s.post is not None:
-                x = apply_plan(x, s.post)
-        else:
-            x = s.fn(params, x) if s.takes_params else s.fn(x)
+        with jax.named_scope(f"{step_kind(s)}:{s.name}"):
+            if isinstance(s, GatherStep):
+                x = apply_plan(x, s.plan)
+                if s.diag is not None:
+                    x = x * jnp.asarray(s.diag, dtype=x.dtype)
+            elif isinstance(s, EinsumStep):
+                with jax.named_scope(f"gather:{s.name}"):
+                    if s.pre is not None:
+                        x = apply_plan(x, s.pre)
+                    if s.pre_diag is not None:
+                        # applied even without a pre plan (identity
+                        # stream-in): the lowered backends honor a bare
+                        # pre_diag too, and the two must agree on every
+                        # expressible program.
+                        x = x * jnp.asarray(s.pre_diag, dtype=x.dtype)
+                h = x.reshape(*x.shape[:-1], *s.reshape_in)
+                op = resolve_operand(s, params)
+                y = jnp.einsum(s.spec, h, jnp.asarray(op, dtype=h.dtype))
+                x = y.reshape(*y.shape[:-s.out_rank], -1)
+                if s.post is not None:
+                    with jax.named_scope(f"gather:{s.name}"):
+                        x = apply_plan(x, s.post)
+            else:
+                x = s.fn(params, x) if s.takes_params else s.fn(x)
     return x
 
 
@@ -483,16 +498,18 @@ def execute_program(program: ExecProgram, stage_fns: Dict[str, Callable],
     (``(x, stage_params) -> y``, supplied by the backend), mask
     frames-domain outputs when ``valid_frames`` is given, and collect the
     declared outputs (ordered dict, or the bare primary array for
-    ``single`` programs)."""
+    ``single`` programs).  Each stage runs under the named scope of its
+    name."""
     env = {INPUT: x}
     for st in program.stages:
-        vals = [env[i] for i in st.inputs]
-        h = st.combine(*vals) if st.combine is not None else vals[0]
-        sp = (params or {}).get(st.name) if isinstance(params, dict) \
-            else params
-        y = stage_fns[st.name](h, sp)
-        if valid_frames is not None and st.out_type.domain == "frames":
-            y = mask_frames(y, valid_frames, len(st.out_type.suffix))
+        with jax.named_scope(st.name):
+            vals = [env[i] for i in st.inputs]
+            h = st.combine(*vals) if st.combine is not None else vals[0]
+            sp = (params or {}).get(st.name) if isinstance(params, dict) \
+                else params
+            y = stage_fns[st.name](h, sp)
+            if valid_frames is not None and st.out_type.domain == "frames":
+                y = mask_frames(y, valid_frames, len(st.out_type.suffix))
         env[st.name] = y
     if program.single:
         return env[program.outputs[0]]

@@ -31,10 +31,16 @@ Two entry points share the kernel:
     contracts against group ``(r // nb) % G``.  This is the FFT
     butterfly shape — per-twiddle-class (nb, 4) x (4, 4) matmuls — for
     arbitrary gather plans (the graph compiler's fused/folded stages).
+
+``scopes`` (optional): the named scopes of the gather and of the kernel
+call with its layout ops — the graph steps they run for, so the
+compiled program's op metadata tells the fabric pass from the array
+pass.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -59,6 +65,10 @@ def _gather(x, idx, pad_vals, scale):
     return g
 
 
+def _scope(name):
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
 def _kernel(g_ref, w_ref, o_ref):
     # f32 operands contract at full f32 precision, as the reference does
     o_ref[0, 0] = jax.lax.dot_general(
@@ -73,9 +83,11 @@ def _block_lanes(c: int, t: int, n_out: int, itemsize: int) -> int:
     return max(LANES, min(want, cap, MAX_BLOCK_LANES))
 
 
-def _grouped_gemm(x, idx, pad_vals, w, reps, groups, nb, interpret, scale):
+def _grouped_gemm(x, idx, pad_vals, w, reps, groups, nb, interpret, scale,
+                  scopes=None):
     """x: (B, n_in); idx/pad_vals[/scale]: (R, t), R = reps*G*nb rows in
     (reps, G, nb) order; w: (G, t, n_out) -> (B, reps, G, nb, n_out)."""
+    gather_scope, kernel_scope = scopes or (None, None)
     b = x.shape[0]
     r, t = idx.shape
     n_out = w.shape[-1]
@@ -89,46 +101,53 @@ def _grouped_gemm(x, idx, pad_vals, w, reps, groups, nb, interpret, scale):
         return jnp.pad(a, ((0, 0), (0, 0), (0, cp - c)),
                        constant_values=fill)
 
-    g = _gather(x, to_lanes(idx, -1), to_lanes(pad_vals, 0),
-                None if scale is None else to_lanes(scale, 0))
-    y = pl.pallas_call(
-        _kernel,
-        grid=(b, groups, cp // bl),
-        in_specs=[pl.BlockSpec((1, 1, t, bl), lambda i, j, k: (i, j, 0, k)),
-                  pl.BlockSpec((1, n_out, t), lambda i, j, k: (j, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1, n_out, bl),
-                               lambda i, j, k: (i, j, 0, k)),
-        out_shape=jax.ShapeDtypeStruct((b, groups, n_out, cp), x.dtype),
-        interpret=interpret,
-        name="shuffle_gemm",
-    )(g, jnp.swapaxes(w, 1, 2).astype(x.dtype))
-    y = y[..., :c].reshape(b, groups, n_out, reps, nb)
-    return y.transpose(0, 3, 1, 4, 2)
+    with _scope(gather_scope):
+        g = _gather(x, to_lanes(idx, -1), to_lanes(pad_vals, 0),
+                    None if scale is None else to_lanes(scale, 0))
+    with _scope(kernel_scope):
+        y = pl.pallas_call(
+            _kernel,
+            grid=(b, groups, cp // bl),
+            in_specs=[pl.BlockSpec((1, 1, t, bl),
+                                   lambda i, j, k: (i, j, 0, k)),
+                      pl.BlockSpec((1, n_out, t), lambda i, j, k: (j, 0, 0))],
+            out_specs=pl.BlockSpec((1, 1, n_out, bl),
+                                   lambda i, j, k: (i, j, 0, k)),
+            out_shape=jax.ShapeDtypeStruct((b, groups, n_out, cp), x.dtype),
+            interpret=interpret,
+            name="shuffle_gemm",
+        )(g, jnp.swapaxes(w, 1, 2).astype(x.dtype))
+        y = y[..., :c].reshape(b, groups, n_out, reps, nb)
+        return y.transpose(0, 3, 1, 4, 2)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "scopes"))
 def shuffle_gemm_blocks(x: jax.Array, idx: jax.Array, pad_vals: jax.Array,
                         w: jax.Array, interpret: bool = True,
-                        scale: jax.Array | None = None) -> jax.Array:
+                        scale: jax.Array | None = None,
+                        scopes: tuple | None = None) -> jax.Array:
     """x: (B, n_in); idx/pad_vals[/scale]: (R, t); w: (t, n_out) ->
     (B, R, n_out)."""
     r = idx.shape[0]
     y = _grouped_gemm(x, idx, pad_vals, w[None], r, 1, 1, interpret,
-                      scale)
-    return y.reshape(x.shape[0], r, w.shape[-1])
+                      scale, scopes)
+    with _scope(scopes and scopes[1]):
+        return y.reshape(x.shape[0], r, w.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("reps", "groups", "nb",
-                                             "interpret"))
+                                             "interpret", "scopes"))
 def shuffle_gemm_grouped_blocks(x: jax.Array, idx: jax.Array,
                                 pad_vals: jax.Array, w: jax.Array,
                                 reps: int, groups: int, nb: int,
                                 interpret: bool = True,
-                                scale: jax.Array | None = None
+                                scale: jax.Array | None = None,
+                                scopes: tuple | None = None
                                 ) -> jax.Array:
     """x: (B, n_in); idx/pad_vals[/scale]: (R, t) with R = reps*G*nb in
     (reps, G, nb) row order; w: (G, t, n_out) -> (B, R * n_out) flat in
     the same row order (the einsum's natural ``...fjbo`` layout)."""
     y = _grouped_gemm(x, idx, pad_vals, w, reps, groups, nb, interpret,
-                      scale)
-    return y.reshape(x.shape[0], -1)
+                      scale, scopes)
+    with _scope(scopes and scopes[1]):
+        return y.reshape(x.shape[0], -1)

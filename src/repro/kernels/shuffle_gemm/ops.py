@@ -25,26 +25,28 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
 
 def shuffle_gemm(x: jax.Array, plan: ShufflePlan, w: jax.Array,
                  rows: int, interpret: Optional[bool] = None,
-                 diag=None) -> jax.Array:
+                 diag=None, scopes=None) -> jax.Array:
     """out = reshape(apply_plan(x) (* diag), (rows, t)) @ w: an XLA
     gather feeding one GEMM kernel.
 
     x: (..., n_in); plan.n_out == rows * t; w: (t, n_out); diag is an
     optional per-element scale of the gathered stream (a GatherStep /
     EinsumStep ``diag``).  Returns (..., rows, n_out).  ``interpret=None``
-    resolves via :func:`repro.kernels.interpret_default`.
+    resolves via :func:`repro.kernels.interpret_default`.  ``scopes``
+    optionally names the gather's and the kernel call's ``jax``
+    named scopes (a ``(gather, kernel)`` pair of strings).
 
     Differentiable in ``x`` and ``w`` via a custom VJP whose backward
     pass runs on the same kernels (see shuffle_gemm/vjp.py).
     """
     return gemm_call(x, plan, w, rows, _resolve_interpret(interpret),
-                     diag)
+                     diag, scopes)
 
 
 def shuffle_gemm_grouped(x: jax.Array, plan: ShufflePlan, w: jax.Array,
                          reps: int, groups: int, nb: int,
                          interpret: Optional[bool] = None,
-                         diag=None) -> jax.Array:
+                         diag=None, scopes=None) -> jax.Array:
     """Grouped-operand variant: plan rows have flat layout
     ``(reps, groups, nb)`` and row ``r`` contracts against
     ``w[(r // nb) % groups]`` — the FFT-butterfly shape (per-twiddle-class
@@ -54,7 +56,8 @@ def shuffle_gemm_grouped(x: jax.Array, plan: ShufflePlan, w: jax.Array,
     w: (groups, t, n_out).  Returns the flat (..., R * n_out) result in
     row order (the consuming einsum's natural layout).
 
-    Differentiable in ``x`` and ``w`` via a custom VJP (vjp.py).
+    Differentiable in ``x`` and ``w`` via a custom VJP (vjp.py);
+    ``scopes`` as in :func:`shuffle_gemm`.
     """
     return grouped_call(x, plan, w, reps, groups, nb,
-                        _resolve_interpret(interpret), diag)
+                        _resolve_interpret(interpret), diag, scopes)

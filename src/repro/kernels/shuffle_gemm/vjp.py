@@ -59,13 +59,13 @@ def plan_blocks(plan: ShufflePlan, diag, rows: int, dtype):
 
 
 def blocks_call(xb: jax.Array, idx, pads, w: jax.Array,
-                interpret: bool, scale=None) -> jax.Array:
+                interpret: bool, scale=None, scopes=None) -> jax.Array:
     """Run the shared-operand kernel on host-built blocks.
     ``xb``: (B, n_in) -> (B, rows, n_out)."""
     return shuffle_gemm_blocks(
         xb, jnp.asarray(idx), jnp.asarray(pads, dtype=xb.dtype), w,
         interpret=interpret,
-        scale=None if scale is None else jnp.asarray(scale))
+        scale=None if scale is None else jnp.asarray(scale), scopes=scopes)
 
 
 def _identity_blocks(rows: int, t: int):
@@ -127,13 +127,13 @@ def _adjoint_dx(dg_flat: jax.Array, plan: ShufflePlan, n_in: int, diag,
 
 
 def gemm_call(x: jax.Array, plan: ShufflePlan, w: jax.Array, rows: int,
-              interpret: bool, diag) -> jax.Array:
+              interpret: bool, diag, scopes=None) -> jax.Array:
     """:func:`repro.kernels.shuffle_gemm` body with a custom VJP.
     x: (..., n_in), w: (t, n_out) -> (..., rows, n_out)."""
     t, idx, pads, scale = plan_blocks(plan, diag, rows, x.dtype)
 
     def impl(xb, w):
-        return blocks_call(xb, idx, pads, w, interpret, scale)
+        return blocks_call(xb, idx, pads, w, interpret, scale, scopes)
 
     def fwd(xb, w):
         return impl(xb, w), (xb, w)
@@ -168,7 +168,7 @@ def gemm_call(x: jax.Array, plan: ShufflePlan, w: jax.Array, rows: int,
 
 def grouped_call(x: jax.Array, plan: ShufflePlan, w: jax.Array,
                  reps: int, groups: int, nb: int, interpret: bool,
-                 diag) -> jax.Array:
+                 diag, scopes=None) -> jax.Array:
     """:func:`repro.kernels.shuffle_gemm_grouped` body with a custom
     VJP.  x: (..., n_in), w: (groups, t, n_out) -> (..., R * n_out)
     with R = reps * groups * nb."""
@@ -179,7 +179,8 @@ def grouped_call(x: jax.Array, plan: ShufflePlan, w: jax.Array,
         return shuffle_gemm_grouped_blocks(
             xb, jnp.asarray(idx), jnp.asarray(pads, dtype=xb.dtype), w,
             reps=reps, groups=groups, nb=nb, interpret=interpret,
-            scale=None if scale is None else jnp.asarray(scale))
+            scale=None if scale is None else jnp.asarray(scale),
+            scopes=scopes)
 
     def fwd(xb, w):
         return impl(xb, w), (xb, w)

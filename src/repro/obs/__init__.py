@@ -1,29 +1,36 @@
-"""SigTrace observability: chrome-tracing + metrics for the SigStream stack.
+"""SigTrace observability: spans, metrics and the serving report.
 
 Three pieces (see ``docs/observability.md``):
 
-  * :mod:`repro.obs.trace`   — per-tick Chrome Trace Event recorder
-    (spans / instants / counter tracks, pid/tid lanes per component),
-    exported as ``chrome://tracing`` / Perfetto-loadable JSON;
+  * :mod:`repro.obs.trace`   — Chrome Trace Event recorder (spans,
+    instants, counter tracks; one pid/tid lane per component), exported
+    as ``chrome://tracing`` / Perfetto-loadable JSON;
   * :mod:`repro.obs.metrics` — process-wide counters / gauges /
     p50-p95-p99 histograms fed by hooks in the serving, streaming and
     backend layers;
   * :mod:`repro.obs.report`  — the post-run latency / occupancy /
     cache-hit-rate summary built from those metrics.
 
-**The switch.**  Everything is off by default and *zero-cost when off*:
-every instrumentation site in the hot paths is guarded by
+**One span call, two sinks.**  Every timed block in the program is
 
-    if obs.ENABLED:
-        obs.complete("SignalService", "bucket_fill", t0, args={...})
+    with obs.span("SignalService", "wave.stack", wave=n) as sp:
+        ...
+        sp.set(pad_waste=w)          # values known only at exit
 
-— one module-attribute load and one branch, no allocation, no calls.
-:func:`enable` / :func:`disable` flip the flag; :func:`enable_from_env`
+While a ``jax.profiler`` session records, the span is a
+``TraceAnnotation`` named ``repro.<name>`` in the profiler's own trace,
+so it lies on the same clock as the device ops.  While :data:`ENABLED`
+is set, it is also an ``X`` event of the SigTrace Chrome JSON.  With
+neither on, :func:`span` returns one shared no-op context: no
+allocation, no clock reading.  Span args are ints or strings.
+
+Counters, gauges, histograms and instants are fed only while
+:data:`ENABLED` is set (``if obs.ENABLED:`` at each site).
+:func:`enable` / :func:`disable` flip it; :func:`enable_from_env`
 honors ``REPRO_TRACE`` (``1``/``true`` to enable, any other non-empty
-value is used as the trace-export path) so benches and services can be
-traced without touching code.  Instrumentation never changes computed
-arrays — hooks record host-side integers (shapes, counts, clock reads)
-only, outside the jitted programs.
+value is used as the trace-export path).  Instrumentation never changes
+computed arrays: hooks record host-side numbers only, outside the jitted
+programs.
 """
 
 from __future__ import annotations
@@ -31,6 +38,8 @@ from __future__ import annotations
 import os
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 from .trace import (Tracer, get_tracer, reset_tracer, validate_trace,
                     TraceError)
@@ -40,7 +49,7 @@ from .report import REPORT_SCHEMA_VERSION, build_report, render_report
 
 __all__ = ["ENABLED", "enable", "disable", "enabled", "enable_from_env",
            "reset", "now", "tracer", "metrics",
-           "complete", "instant", "counter_track", "span",
+           "instant", "span", "NO_SPAN",
            "Tracer", "get_tracer", "reset_tracer", "validate_trace",
            "TraceError", "Counter", "Gauge", "Histogram",
            "MetricsRegistry", "get_registry", "reset_registry",
@@ -102,9 +111,12 @@ def default_trace_path() -> str:
     return _trace_path or _DEFAULT_TRACE_PATH
 
 
-# -- hook helpers (call ONLY under ``if obs.ENABLED:``) ---------------------
+# -- hook helpers ----------------------------------------------------------
 
 now = time.perf_counter_ns
+
+# True while a jax.profiler session records (one C++ call, no allocation)
+_profiling = TraceAnnotation.is_enabled
 
 
 def tracer() -> Tracer:
@@ -115,19 +127,75 @@ def metrics() -> MetricsRegistry:
     return get_registry()
 
 
-def complete(lane: str, name: str, t0_ns: int, **args) -> None:
-    """Record an X span begun at ``t0_ns`` (from :func:`now`)."""
-    get_tracer().complete(lane, name, t0_ns, args or None)
-
-
 def instant(lane: str, name: str, **args) -> None:
+    """Record an ``i`` event (call ONLY under ``if obs.ENABLED:``)."""
     get_tracer().instant(lane, name, args or None)
 
 
-def counter_track(name: str, **values) -> None:
-    get_tracer().counter(name, values)
+class _NoSpan:
+    """The span while neither sink is on: enter, set and exit do nothing,
+    and it is false, so a site can skip computing args for it
+    (``if sp: sp.set(...)``)."""
+
+    __slots__ = ()
+
+    def __bool__(self):
+        return False
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+class _Span:
+    """One span into the sinks that were on when it was made: the
+    profiler's ``TraceAnnotation`` and/or the SigTrace ``X`` event."""
+
+    __slots__ = ("lane", "name", "args", "_annotation", "_record", "_t0")
+
+    def __init__(self, lane, name, args, profiling, record):
+        self.lane, self.name, self.args = lane, name, args
+        self._annotation = (TraceAnnotation("repro." + name, **args)
+                            if profiling else None)
+        self._record = record
+        self._t0 = 0
+
+    def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._record:
+            self._t0 = time.perf_counter_ns()
+        return self
+
+    def set(self, **args) -> None:
+        """Add args known only inside the span (bytes, pad waste)."""
+        if self._annotation is not None:
+            self._annotation.set_metadata(**args)
+        self.args.update(args)
+
+    def __exit__(self, *exc):
+        if self._record:
+            get_tracer().complete(self.lane, self.name, self._t0,
+                                  self.args or None)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        return False
 
 
 def span(lane: str, name: str, **args):
-    """Context-manager span (user code / non-hot paths)."""
-    return get_tracer().span(lane, name, args or None)
+    """Context manager timing a block: ``repro.<name>`` in the profiler's
+    trace while a profiler session records, an ``X`` event named
+    ``name`` on ``lane`` of the SigTrace JSON while :data:`ENABLED`;
+    :data:`NO_SPAN` while neither is on."""
+    profiling = _profiling()
+    if not (profiling or ENABLED):
+        return NO_SPAN
+    return _Span(lane, name, args, profiling, ENABLED)

@@ -3,36 +3,34 @@
 One process-wide :class:`Tracer` collects timeline events from the
 serving / streaming / backend instrumentation hooks and exports them in
 the Chrome Trace Event Format (the ``{"traceEvents": [...]}`` JSON that
-``chrome://tracing`` and Perfetto load directly).  Design constraints,
-in order:
+``chrome://tracing`` and Perfetto load directly).  It is the JSON sink
+of :func:`repro.obs.span`, for runs without a ``jax.profiler`` session.
+Design constraints, in order:
 
-  * **zero-cost when off** — every instrumentation site in the hot
-    paths guards itself with ``if obs.ENABLED:`` (one module-attribute
-    load + branch); nothing here is even called while tracing is
-    disabled.  Timestamps are taken with ``time.perf_counter_ns`` and
-    events are plain dicts appended under a lock, so an *enabled*
-    tracer stays host-side cheap and never touches device arrays.
+  * **nothing runs while off** — hooks reach the tracer only while
+    ``obs.ENABLED`` is set.  Timestamps are taken with
+    ``time.perf_counter_ns`` and events are plain dicts appended under a
+    lock, so an *enabled* tracer stays host-side cheap and never touches
+    device arrays.
   * **lanes, not threads** — ``tid`` identifies a logical component
-    (``CoScheduler``, ``SignalService``, ``DecodeWave``, ``Streaming``,
-    one lane per served graph), mapped to stable small integers and
-    named via ``M`` metadata events, so a serving tick reads as
-    parallel swimlanes in the viewer regardless of the host threading.
-  * **well-formed by construction** — block spans are recorded as
-    ``X`` *complete* events (begin timestamp + duration captured at
-    exit), so a crash mid-span can at worst lose the span, never
-    unbalance the stream; the explicit :meth:`Tracer.begin` /
-    :meth:`Tracer.end` API exists for spans that cannot wrap a block
-    and is validated by :func:`validate_trace`.
+    (``CoScheduler``, ``SigSched``, ``SignalService``, ``DecodeWave``,
+    ``Streaming``, ``SigQuant``), mapped to stable small integers and
+    named via ``M`` metadata events, so a serving tick reads as parallel
+    swimlanes in the viewer regardless of the host threading.
+  * **well-formed by construction** — spans are recorded as ``X``
+    *complete* events (begin timestamp + duration captured at exit), so
+    a crash mid-span can at worst lose the span, never unbalance the
+    stream.
 
-Event vocabulary used by the instrumentation (see
-``docs/observability.md`` for the walkthrough of one serving tick):
+Event vocabulary (``docs/observability.md`` walks through one wave):
 
-  ``X``  spans    tick / bucket_fill / core_call / prefill /
-                  decode_step / stream_tick / stream_core
-  ``i``  instants compile (per-bucket, with the backend's
-                  ``lowering_report`` route counts), admit
-  ``C``  counters occupancy (dsp/llm cycle split), queue_depth,
-                  plan_cache hit rate per backend
+  ``X``  spans    sched.dispatch, wave, wave.stack / h2d / launch /
+                  fetch / finish, compile, stream.tick, stream.core,
+                  checkpoint, cosched.tick, engine.prefill,
+                  engine.decode_step, quant.calibrate, quant.solve_widths
+  ``i``  instants admit, defer, starvation_pick, device_loss
+  ``C``  counters occupancy, dsp_share, device_occupancy, scheduler,
+                  plan_cache/<backend> hit rate
 """
 
 from __future__ import annotations
@@ -56,7 +54,6 @@ class Tracer:
         self._events: List[dict] = []
         self._lanes: Dict[str, int] = {}
         self._t0 = time.perf_counter_ns()
-        self._begin_stacks: Dict[int, List[str]] = {}
 
     # -- time ---------------------------------------------------------------
     @staticmethod
@@ -96,36 +93,6 @@ class Tracer:
             ev["args"] = args
         self._append(ev)
 
-    def begin(self, lane: str, name: str,
-              args: Optional[dict] = None, cat: str = "repro") -> None:
-        tid = self.lane(lane)
-        ev = {"ph": "B", "pid": _PID, "tid": tid, "name": name,
-              "cat": cat, "ts": self._ts(time.perf_counter_ns())}
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self._events.append(ev)
-            self._begin_stacks.setdefault(tid, []).append(name)
-
-    def end(self, lane: str, args: Optional[dict] = None,
-            cat: str = "repro") -> None:
-        tid = self.lane(lane)
-        with self._lock:
-            stack = self._begin_stacks.get(tid, [])
-            if not stack:
-                raise TraceError(f"end() without begin() on lane {lane!r}")
-            name = stack.pop()
-            ev = {"ph": "E", "pid": _PID, "tid": tid, "name": name,
-                  "cat": cat, "ts": self._ts(time.perf_counter_ns())}
-            if args:
-                ev["args"] = args
-            self._events.append(ev)
-
-    def span(self, lane: str, name: str, args: Optional[dict] = None,
-             cat: str = "repro"):
-        """Context manager recording one ``X`` span around a block."""
-        return _Span(self, lane, name, args, cat)
-
     def instant(self, lane: str, name: str,
                 args: Optional[dict] = None, cat: str = "repro") -> None:
         ev = {"ph": "i", "pid": _PID, "tid": self.lane(lane),
@@ -152,7 +119,6 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
-            self._begin_stacks.clear()
             self._t0 = time.perf_counter_ns()
 
     def _metadata_events(self) -> List[dict]:
@@ -181,23 +147,6 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(self.to_dict(), f)
         return path
-
-
-class _Span:
-    __slots__ = ("tracer", "lane", "name", "args", "cat", "_t0")
-
-    def __init__(self, tracer, lane, name, args, cat):
-        self.tracer, self.lane, self.name = tracer, lane, name
-        self.args, self.cat = args, cat
-
-    def __enter__(self):
-        self._t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc):
-        self.tracer.complete(self.lane, self.name, self._t0,
-                             self.args, self.cat)
-        return False
 
 
 # --------------------------------------------------------------------------
@@ -233,8 +182,8 @@ def validate_trace(path_or_dict) -> dict:
     """Validate a Chrome Trace Event JSON file (or already-loaded dict).
 
     Checks the invariants the instrumentation promises: the container
-    shape, per-``tid`` balanced ``B``/``E`` nesting, non-negative ``X``
-    durations, per-``tid`` monotonic timestamps in record order for
+    shape, only the phases the tracer writes (``X``, ``i``, ``C``,
+    ``M``), non-negative ``X`` durations, per-``tid`` monotonic timestamps in record order for
     non-``X`` phases, and non-negative counter values.  Returns summary
     stats (event counts per phase, lanes) on success; raises
     :class:`TraceError` otherwise.
@@ -246,7 +195,6 @@ def validate_trace(path_or_dict) -> dict:
             doc = json.load(f)
     if "traceEvents" not in doc or not isinstance(doc["traceEvents"], list):
         raise TraceError("missing traceEvents list")
-    per_tid_stack: Dict[int, List[str]] = {}
     per_tid_last_ts: Dict[int, float] = {}
     phases: Dict[str, int] = {}
     lanes = set()
@@ -255,6 +203,9 @@ def validate_trace(path_or_dict) -> dict:
         phases[ph] = phases.get(ph, 0) + 1
         if ph == "M":
             continue
+        if ph not in ("X", "i", "C"):
+            raise TraceError(f"event {i} has phase {ph!r}, which the "
+                             f"tracer does not write: {ev}")
         for field in ("pid", "tid", "ts", "name"):
             if field not in ev:
                 raise TraceError(f"event {i} missing {field!r}: {ev}")
@@ -276,21 +227,11 @@ def validate_trace(path_or_dict) -> dict:
                 raise TraceError(
                     f"event {i} ts {ts} < previous {last} on tid {tid}")
             per_tid_last_ts[tid] = ts
-        if ph == "B":
-            per_tid_stack.setdefault(tid, []).append(ev["name"])
-        elif ph == "E":
-            stack = per_tid_stack.get(tid, [])
-            if not stack:
-                raise TraceError(f"E event {i} without matching B: {ev}")
-            stack.pop()
-        elif ph == "C":
+        if ph == "C":
             for k, v in ev.get("args", {}).items():
                 if not isinstance(v, (int, float)) or v < 0:
                     raise TraceError(
                         f"counter {ev['name']!r} series {k!r} has "
                         f"non-numeric/negative value {v!r}")
-    unbalanced = {t: s for t, s in per_tid_stack.items() if s}
-    if unbalanced:
-        raise TraceError(f"unbalanced B events: {unbalanced}")
     return {"events": sum(v for k, v in phases.items() if k != "M"),
             "phases": phases, "lanes": sorted(lanes)}

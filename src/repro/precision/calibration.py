@@ -9,8 +9,8 @@ precisely the step a :class:`~repro.signal.backends.PrecisionPolicy` can
 name — and executes each step on the reference path, so observation
 never perturbs outputs.  It is strictly opt-in: the normal compile /
 stream / serve routes never construct the observer, so calibration is
-zero-cost when off; a single ``obs.complete`` span records each pass
-when SigTrace is enabled.
+zero-cost when off; a single ``quant.calibrate`` span
+(:func:`repro.obs.span`) records each pass.
 
 Per row-uniform (int-routable) step group the record accumulates, over
 all calibration batches:
@@ -298,19 +298,17 @@ def calibrate(compiled, batches: Sequence[np.ndarray], params=None,
                                params=params)
     record._reach = compiled._stage_reach()
     observed = compiled.with_backend(_ObserverBackend(record, ladder))
-    t0 = obs.now() if obs.ENABLED else 0
-    for b in batches:
-        observed(jnp.asarray(b), params)       # eager: stats land per step
-    reference = (compiled if compiled.backend.name == "reference"
-                 else compiled.with_backend("reference"))
-    record.batches = batches
-    record.holdout = holdout
-    record.baselines = [
-        jax.tree_util.tree_map(np.asarray,
-                               reference(jnp.asarray(b), params))
-        for b in holdout]
-    if obs.ENABLED:
-        obs.complete("SigQuant", "calibrate", t0, graph=compiled.name,
-                     batches=len(batches), holdout=len(holdout),
-                     steps=len(record.steps))
+    with obs.span("SigQuant", "quant.calibrate", graph=compiled.name,
+                  batches=len(batches), holdout=len(holdout)) as sp:
+        for b in batches:
+            observed(jnp.asarray(b), params)   # eager: stats land per step
+        reference = (compiled if compiled.backend.name == "reference"
+                     else compiled.with_backend("reference"))
+        record.batches = batches
+        record.holdout = holdout
+        record.baselines = [
+            jax.tree_util.tree_map(np.asarray,
+                                   reference(jnp.asarray(b), params))
+            for b in holdout]
+        sp.set(steps=len(record.steps))
     return record

@@ -73,7 +73,13 @@ def solve_widths(record: CalibrationRecord, budget: float = 1e-2,
     a :class:`PrecisionPolicy` naming every admissible GEMM-shaped step.
     Raises ``ValueError`` when the budget is unreachable even with every
     step at its widest admissible widths."""
-    t0 = obs.now() if obs.ENABLED else 0
+    with obs.span("SigQuant", "quant.solve_widths", graph=record.graph,
+                  budget=budget) as sp:
+        return _solve(record, budget, ladder, interpret, max_rounds, sp)
+
+
+def _solve(record, budget, ladder, interpret, max_rounds,
+           sp) -> PrecisionPolicy:
     admissible = {
         name: [tuple(p) for p in ladder if record.steps[name].fits(p)]
         for name in record.gemm_steps()}
@@ -92,11 +98,7 @@ def solve_widths(record: CalibrationRecord, budget: float = 1e-2,
         worst = max(errs, key=lambda k: errs[k])
         if errs[worst] <= budget:
             record.assert_no_overflow(policy)
-            if obs.ENABLED:
-                obs.complete("SigQuant", "solve_widths", t0,
-                             graph=record.graph, budget=budget,
-                             steps=len(admissible),
-                             worst_err=errs[worst])
+            sp.set(steps=len(admissible), worst_err=errs[worst])
             return policy
         grow = [n for n in admissible
                 if level[n] + 1 < len(admissible[n])

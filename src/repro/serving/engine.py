@@ -170,20 +170,21 @@ class DecodeWave:
     def _prefill(self) -> None:
         if not self.reqs:
             raise ValueError("DecodeWave needs at least one request")
-        _t0 = obs.now() if obs.ENABLED else 0
         engine = self.engine
-        prompts = [list(r.prompt) + o for r, o in zip(self.reqs, self.outs)]
-        self.max_new = max(r.max_new - len(o)
-                           for r, o in zip(self.reqs, self.outs))
-        logits, self.cache, plen = engine.prefill_prompts(prompts,
-                                                          self.max_new)
-        self.prefill_tokens = plen            # for scheduler cost accounting
-        self.rng = jax.random.PRNGKey(0)
-        self.cur = engine._sample(logits[:, -1], self.rng)
-        self.steps = 0
+        with obs.span("DecodeWave", "engine.prefill",
+                      size=len(self.reqs)) as sp:
+            prompts = [list(r.prompt) + o
+                       for r, o in zip(self.reqs, self.outs)]
+            self.max_new = max(r.max_new - len(o)
+                               for r, o in zip(self.reqs, self.outs))
+            logits, self.cache, plen = engine.prefill_prompts(prompts,
+                                                              self.max_new)
+            self.prefill_tokens = plen        # for scheduler cost accounting
+            self.rng = jax.random.PRNGKey(0)
+            self.cur = engine._sample(logits[:, -1], self.rng)
+            self.steps = 0
+            sp.set(prefill_tokens=plen)
         if obs.ENABLED:
-            obs.complete("DecodeWave", "prefill", _t0,
-                         size=len(self.reqs), prefill_tokens=plen)
             m = obs.metrics()
             m.counter("engine.prefills").inc()
             m.gauge("engine.decode_occupancy").set(
@@ -206,7 +207,21 @@ class DecodeWave:
         return max(0, cap - len(self.reqs)) + finished
 
     def step(self) -> None:
-        _t0 = obs.now() if obs.ENABLED else 0
+        with obs.span("DecodeWave", "engine.decode_step",
+                      size=len(self.reqs)) as sp:
+            live = self._step()
+            if sp:
+                sp.set(step=self.steps, live=live)
+        if obs.ENABLED and not self.done:        # a decode step ran
+            m = obs.metrics()
+            m.counter("engine.decode_steps").inc()
+            # occupancy = rows still generating / engine batch capacity
+            m.gauge("engine.decode_occupancy").set(
+                live / max(1, self.engine.batch_size))
+
+    def _step(self) -> int:
+        """Emit the current tokens and, unless the wave is done, decode
+        the next ones; returns the rows still generating."""
         live = 0
         for i, (r, o) in enumerate(zip(self.reqs, self.outs)):
             if len(o) < r.max_new:
@@ -214,19 +229,12 @@ class DecodeWave:
                 live += 1
         self.steps += 1
         if self.done:
-            return
+            return live
         logits, self.cache = self.engine._decode(
             self.engine.params, self.cache, {"tokens": self.cur[:, None]})
         self.rng, sub = jax.random.split(self.rng)
         self.cur = self.engine._sample(logits[:, -1], sub)
-        if obs.ENABLED:
-            obs.complete("DecodeWave", "decode_step", _t0,
-                         step=self.steps, size=len(self.reqs), live=live)
-            m = obs.metrics()
-            m.counter("engine.decode_steps").inc()
-            # occupancy = rows still generating / engine batch capacity
-            m.gauge("engine.decode_occupancy").set(
-                live / max(1, self.engine.batch_size))
+        return live
 
     def pop_done(self) -> Dict[int, List[int]]:
         """Harvest requests that reached their ``max_new`` and were not

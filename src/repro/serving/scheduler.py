@@ -382,7 +382,13 @@ class SigSched:
         svc = self.service
         if not svc._queue and not self._waves:
             return {}
-        _t0 = obs.now() if obs.ENABLED else 0
+        # ``wave``: the number the service gives the wave this runs
+        with obs.span("SigSched", "sched.dispatch",
+                      wave=svc._wave_seq) as sp:
+            return self._dispatch(sp)
+
+    def _dispatch(self, sp) -> Dict[int, np.ndarray]:
+        svc = self.service
         now = float(svc.est_cycles)
         groups = self._collect_groups()
         if self.promote and self.edf:
@@ -411,7 +417,7 @@ class SigSched:
                 self._waves.append(wave)
             else:
                 return self._run_chunk(chosen, reqs, split=False,
-                                       now=now, t0=_t0)
+                                       now=now, sp=sp)
 
         chunk = wave.requests[: budget] if budget is not None \
             else list(wave.requests)
@@ -425,10 +431,10 @@ class SigSched:
         group = ExecGroup(key=wave.key, length=wave.length,
                           requests=chunk,
                           per_row_cost=chosen.per_row_cost, wave=wave)
-        return self._run_chunk(group, chunk, split=True, now=now, t0=_t0)
+        return self._run_chunk(group, chunk, split=True, now=now, sp=sp)
 
     def _run_chunk(self, group: ExecGroup, reqs: List["SignalRequest"],
-                   split: bool, now: float, t0: int) -> Dict:
+                   split: bool, now: float, sp) -> Dict:
         svc = self.service
         graphs = {r.graph for r in reqs}
         cross = len(graphs) > 1
@@ -457,14 +463,11 @@ class SigSched:
                 "cross_graph_batches": self.stats["cross_graph_batches"],
                 "deferrals": self.stats["deferrals"],
                 "bucket_promotions": self.stats["bucket_promotions"]})
-        results = svc._execute_wave(reqs, group.length)
-        if obs.ENABLED:
+        if sp:
             w = group.wave
-            obs.complete(
-                "SigSched", "dispatch", t0,
-                bucket=group.length, rows=len(reqs),
-                graphs=sorted(graphs), cross_graph=cross,
-                promoted=promoted,
-                chunk=(w.chunks if w is not None else 1),
-                remaining_rows=(len(w.requests) if w is not None else 0))
-        return results
+            sp.set(bucket=group.length, rows=len(reqs),
+                   graphs="+".join(sorted(graphs)), cross_graph=cross,
+                   promoted=promoted,
+                   chunk=(w.chunks if w is not None else 1),
+                   remaining_rows=(len(w.requests) if w is not None else 0))
+        return svc._execute_wave(reqs, group.length)

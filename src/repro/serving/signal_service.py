@@ -269,8 +269,10 @@ class SignalService:
         self._vmap_jitted: Dict[Tuple, object] = {}
         self._cost_cache: Dict[Tuple[str, int], int] = {}
         self._fp_cache: Dict[Tuple[str, int], Optional[Tuple]] = {}
+        self._called: set = set()     # jitted entries called, per shape
         self._queue: List[SignalRequest] = []
         self._seq = 0
+        self._wave_seq = 0            # joins a wave's spans (``wave=``)
         self._sessions: Dict[str, List["StreamSession"]] = {}
         self._sid = 0
         self._ckpt_seq = 0            # next save_checkpoint step number
@@ -324,6 +326,7 @@ class SignalService:
             self._masked_jitted.pop(key, None)
         for key in [k for k in self._vmap_jitted if k[0] == name]:
             del self._vmap_jitted[key]
+        self._called = {sig for sig in self._called if sig[1] != name}
         for cache in (self._cost_cache, self._fp_cache):
             for key in [k for k in cache
                         if k[0] in (name, f"{name}//core")]:
@@ -349,37 +352,43 @@ class SignalService:
     def compiled_for(self, name: str, length: int) -> CompiledSignalGraph:
         key = (name, length)
         if key not in self._compiled:
-            _t0 = obs.now() if obs.ENABLED else 0
-            graph = self._graphs[name].graph
-            self._compiled[key] = graph.compile(length, fuse=self.fuse,
-                                                backend=self.backend)
-            self.stats["compiles"] += 1
-            if obs.ENABLED:
-                self._record_lowering(name, length, self._compiled[key], _t0)
+            with obs.span("SignalService", "compile", graph=name,
+                          bucket=length, entry="graph",
+                          backend=self.backend.name) as sp:
+                graph = self._graphs[name].graph
+                self._compiled[key] = graph.compile(
+                    length, fuse=self.fuse, backend=self.backend)
+                self.stats["compiles"] += 1
+                if obs.ENABLED:
+                    self._record_lowering(self._compiled[key], sp)
         return self._compiled[key]
 
-    def _record_lowering(self, name: str, length: int, compiled,
-                         t0_ns: int) -> None:
-        """Trace one bucket compile and accumulate the backend's
-        fused-vs-emulated route counts (``lowering_report``) into the
-        metrics registry — the runtime side of ``signal_graph_report``'s
-        static pass accounting."""
-        args = {"graph": name, "bucket": length,
-                "backend": self.backend.name}
+    def _record_lowering(self, compiled, sp) -> None:
+        """Accumulate the backend's fused-vs-emulated route counts
+        (``lowering_report``) of one bucket compile into the metrics
+        registry and its ``compile`` span — the runtime side of
+        ``signal_graph_report``'s static pass accounting."""
         lowering = getattr(compiled, "lowering_report", None)
-        if lowering is not None:
-            rep = lowering()
-            m = obs.metrics()
-            pre = f"backend.{rep['name']}"
-            m.counter(f"{pre}.fabric_fused").inc(
-                rep["fabric_passes"]["fused"])
-            m.counter(f"{pre}.fabric_emulated").inc(
-                rep["fabric_passes"]["emulated"])
-            for route, n in rep["array_passes"].items():
-                m.counter(f"{pre}.array_{route}").inc(n)
-            args.update(fabric=rep["fabric_passes"],
-                        array=rep["array_passes"])
-        obs.complete("SignalService", "compile", t0_ns, **args)
+        if lowering is None:
+            return
+        rep = lowering()
+        m = obs.metrics()
+        pre = f"backend.{rep['name']}"
+        counts = {f"fabric_{k}": n for k, n in rep["fabric_passes"].items()}
+        counts.update((f"array_{route}", n)
+                      for route, n in rep["array_passes"].items())
+        for k, n in counts.items():
+            m.counter(f"{pre}.{k}").inc(n)
+        sp.set(**counts)
+
+    def _first_call_span(self, sig: Tuple, **args):
+        """The ``compile`` span around the first call of a jitted entry
+        at one shape — the call that traces and compiles it — and the
+        shared no-op span on every later call."""
+        if sig in self._called:
+            return obs.NO_SPAN
+        self._called.add(sig)
+        return obs.span("SignalService", "compile", **args)
 
     # -- length bucketing ---------------------------------------------------
     def bucket_for(self, name: str, length: int) -> Optional[int]:
@@ -622,88 +631,138 @@ class SignalService:
         Waves mixing rows whose registered params differ execute
         per-row-batched (one jitted ``vmap`` over a stacked params
         pytree) when the pytrees stack, else split into one sub-call
-        per params class (``stats["param_splits"]``)."""
-        _t0 = obs.now() if obs.ENABLED else 0
-        for r in wave:
-            try:
-                self._queue.remove(r)
-            except ValueError:
-                pass                   # claimed earlier into a split wave
+        per params class (``stats["param_splits"]``).
+
+        Spans: ``wave`` around the whole wave, and inside it one per
+        phase — ``wave.stack``, ``wave.h2d``, ``wave.launch`` (up to the
+        jitted call's asynchronous return), ``wave.fetch`` (the wait for
+        the device and the copy back), ``wave.finish`` — all carrying
+        the service's wave number ``wave``."""
+        n = self._wave_seq
+        self._wave_seq += 1
         name = wave[0].graph
-        reg = self._graphs[name]
-        compiled = self.compiled_for(name, length)
-        key = (name, length)
-        lens = [int(r.samples.shape[-1]) for r in wave]
-        padded = any(t != length for t in lens)
-        bucketed = any(getattr(r, "_bucketed", False) for r in wave)
-        masked = padded or (reg.struct is not None
-                            and reg.struct.framer is not None
-                            and bucketed)
-        classes = self._params_classes(wave)
-        if len(classes) > 1 and (self.mesh is not None
-                                 or not self._stackable(classes)):
-            # mismatched params pytrees (or a mesh, whose row sharding
-            # the per-row vmap path does not thread): one sub-call per
-            # params class — the same batched lowering as per-graph
-            # dispatch, so trivially exact.
-            self.stats["param_splits"] += len(classes) - 1
-            results: Dict[int, np.ndarray] = {}
-            for _, idxs in classes:
-                results.update(
-                    self._execute_wave([wave[i] for i in idxs], length))
+        with obs.span("SignalService", "wave", wave=n, graph=name,
+                      bucket=length, rows=len(wave)):
+            with obs.span("SignalService", "wave.stack", wave=n) as sp:
+                for r in wave:
+                    try:
+                        self._queue.remove(r)
+                    except ValueError:
+                        pass           # claimed earlier into a split wave
+                reg = self._graphs[name]
+                compiled = self.compiled_for(name, length)
+                key = (name, length)
+                lens = [int(r.samples.shape[-1]) for r in wave]
+                padded = any(t != length for t in lens)
+                bucketed = any(getattr(r, "_bucketed", False) for r in wave)
+                masked = padded or (reg.struct is not None
+                                    and reg.struct.framer is not None
+                                    and bucketed)
+                classes = self._params_classes(wave)
+                split = len(classes) > 1 and (self.mesh is not None
+                                              or not self._stackable(classes))
+                if not split:
+                    # on a mesh the row count pads to a shard multiple so
+                    # the NamedSharding row partition is even; pad rows
+                    # are zeros (a valid, row-independent input) and
+                    # nothing reads their output.
+                    rows = self.mesh.padded_rows(len(wave)) \
+                        if self.mesh is not None else len(wave)
+                    stack = np.zeros((rows, length), np.float32)
+                    for i, r in enumerate(wave):
+                        stack[i, : lens[i]] = r.samples
+                    # pad waste: the fraction of the stacked (batch,
+                    # bucket) array that is zero padding past each row's
+                    # true length.
+                    if sp or obs.ENABLED:
+                        pad_waste = 1.0 - sum(lens) / float(len(wave)
+                                                            * length)
+                        sp.set(pad_waste=round(pad_waste, 4))
+            if split:
+                # mismatched params pytrees (or a mesh, whose row sharding
+                # the per-row vmap path does not thread): one sub-call per
+                # params class — the same batched lowering as per-graph
+                # dispatch, so trivially exact.
+                self.stats["param_splits"] += len(classes) - 1
+                results: Dict[int, np.ndarray] = {}
+                for _, idxs in classes:
+                    results.update(
+                        self._execute_wave([wave[i] for i in idxs], length))
+                return results
+
+            with obs.span("SignalService", "wave.h2d", wave=n,
+                          bytes=stack.nbytes):
+                batch = self.mesh.shard(stack) if self.mesh is not None \
+                    else jnp.asarray(stack)
+            with obs.span("SignalService", "wave.launch", wave=n):
+                out = self._launch(key, compiled, reg, batch, lens, wave,
+                                   masked, classes)
+            with obs.span("SignalService", "wave.fetch", wave=n) as sp:
+                out = _to_host(out)
+                if sp or obs.ENABLED:
+                    d2h = sum(a.nbytes for a in jax.tree_util.tree_leaves(out))
+                    sp.set(bytes=d2h)
+            with obs.span("SignalService", "wave.finish", wave=n):
+                self.stats["bucketed" if masked else "exact"] += 1
+                self.stats["batches"] += 1
+                self.est_cycles += self.group_cost(key, batch=len(wave))
+                self.wall_cycles += self._charge_devices(self.group_cost(key),
+                                                         len(wave))
+                results = {}
+                for i, r in enumerate(wave):
+                    r.done = True
+                    results[r.rid] = self._request_result(
+                        compiled, self._graphs[r.graph], out, i, lens[i])
+                if obs.ENABLED:
+                    m = obs.metrics()
+                    m.histogram("service.pad_waste").record(pad_waste)
+                    m.counter("service.h2d_bytes").inc(stack.nbytes)
+                    m.counter("service.d2h_bytes").inc(d2h)
+                    self._record_emits(compiled, wave)
             return results
 
-        # on a mesh the row count pads to a shard multiple so the
-        # NamedSharding row partition is even; pad rows are zeros (a
-        # valid, row-independent input) and nothing reads their output.
-        rows = self.mesh.padded_rows(len(wave)) if self.mesh is not None \
-            else len(wave)
-        stack = np.zeros((rows, length), np.float32)
-        for i, r in enumerate(wave):
-            stack[i, : lens[i]] = r.samples
-        batch = self.mesh.shard(stack) if self.mesh is not None \
-            else jnp.asarray(stack)
-        if obs.ENABLED:
-            # pad waste: the fraction of the stacked (batch, bucket)
-            # array that is zero padding past each row's true length.
-            pad_waste = 1.0 - sum(lens) / float(len(wave) * length)
-            obs.complete("SignalService", "bucket_fill", _t0,
-                         graph=name, bucket=length, batch=len(wave),
-                         pad_waste=round(pad_waste, 4))
-            obs.metrics().histogram("service.pad_waste").record(pad_waste)
-            _t1 = obs.now()
-        else:
-            _t1 = _t0
-
+    def _launch(self, key, compiled, reg, batch, lens, wave, masked,
+                classes):
+        """Call the wave's jitted entry — the per-row-params ``vmap``,
+        the masked program or the plain one — and return its device
+        result without waiting for it.  The first call of an entry at a
+        row count is the one that compiles it: a ``compile`` span."""
+        struct = reg.struct
+        mask = masked and struct is not None and struct.framer is not None
+        if mask:
+            # valid-frame counts per row are traced so one compile serves
+            # every length mix in the bucket; sharded batches carry zero
+            # pad rows past the wave: 0 valid frames masks every frame of
+            # a pad row (an all-zero result nothing reads back).
+            counts = [struct.valid_frames(t) for t in lens]
+            counts += [0] * (batch.shape[0] - len(counts))
+            vf = jnp.asarray(counts, jnp.int32)
         if len(classes) > 1:
-            out = self._run_per_row_params(key, compiled, reg, batch,
-                                           lens, wave, masked)
-        elif masked:
-            out = self._run_masked(key, compiled, reg, batch, lens,
-                                   classes[0][0])
+            entry, call = "vmap", self._vmap_call(key, compiled, mask)
+            params = self._row_params(wave)
+        elif mask:
+            entry, call = "masked", self._masked_call(key, compiled)
+            params = classes[0][0]
         else:
-            out = _to_host(self._plain_call(key, compiled)(batch,
-                                                           classes[0][0]))
-        self.stats["bucketed" if masked else "exact"] += 1
+            # a pure sample chain (no framer): causal stages never read
+            # past a row's valid prefix, so padding needs no masking —
+            # only trimming.
+            entry, call = "plain", self._plain_call(key, compiled)
+            params = classes[0][0]
+        args = (batch, vf, params) if mask else (batch, params)
+        with self._first_call_span((entry, *key, batch.shape[0]),
+                                   graph=key[0], bucket=key[1],
+                                   rows=batch.shape[0], entry=entry):
+            return call(*args)
 
-        self.stats["batches"] += 1
-        self.est_cycles += self.group_cost(key, batch=len(wave))
-        self.wall_cycles += self._charge_devices(self.group_cost(key),
-                                                 len(wave))
-        results = {}
-        for i, r in enumerate(wave):
-            r.done = True
-            results[r.rid] = self._request_result(
-                compiled, self._graphs[r.graph], out, i, lens[i])
-        if obs.ENABLED:
-            obs.complete(f"graph/{name}", "core_call", _t1,
-                         bucket=length, batch=len(wave), masked=masked,
-                         graphs=sorted({r.graph for r in wave}))
-            self._record_emits(compiled, wave)
-        return results
+    def _row_params(self, wave):
+        """The wave's per-row registered params, stacked on a leading
+        row axis (the per-row ``vmap``'s params argument)."""
+        return jax.tree_util.tree_map(
+            lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]),
+            *[self._graphs[r.graph].params for r in wave])
 
-    def _run_per_row_params(self, key, compiled, reg, batch, lens, wave,
-                            masked):
+    def _vmap_call(self, key, compiled, mask: bool):
         """Cross-graph wave whose member graphs registered DIFFERENT
         params: one jitted ``vmap`` over (row, valid_frames, per-row
         params) — each row computes with its own graph's params, in one
@@ -712,12 +771,6 @@ class SignalService:
         results stay within the bucketing exactness contract (asserted
         bit-exact for the streamable graph class in
         tests/test_scheduler.py)."""
-        row_params = [self._graphs[r.graph].params for r in wave]
-        pstack = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]),
-            *row_params)
-        struct = reg.struct
-        mask = masked and struct is not None and struct.framer is not None
         vkey = (*key, mask)
         if vkey not in self._vmap_jitted:
             if mask:
@@ -727,11 +780,7 @@ class SignalService:
                 def call(x, p):
                     return compiled(x, p)
             self._vmap_jitted[vkey] = jax.jit(jax.vmap(call))
-        if mask:
-            vf = jnp.asarray([struct.valid_frames(t) for t in lens],
-                             jnp.int32)
-            return _to_host(self._vmap_jitted[vkey](batch, vf, pstack))
-        return _to_host(self._vmap_jitted[vkey](batch, pstack))
+        return self._vmap_jitted[vkey]
 
     def _record_emits(self, compiled, wave) -> None:
         """Admission->emit latency per request, attributed per graph and
@@ -783,15 +832,10 @@ class SignalService:
                 else self.mesh.row_parallel(compiled.__call__, 1)
         return self._jitted[key]
 
-    def _run_masked(self, key, compiled, reg, batch, lens,
-                    params) -> np.ndarray:
-        """Masked/padded execution: valid-frame counts per row are traced
-        so one compile serves every length mix in the bucket."""
-        struct = reg.struct
-        if struct.framer is None:
-            # pure sample chain: causal stages never read past a row's
-            # valid prefix, so padding needs no masking — only trimming.
-            return _to_host(self._plain_call(key, compiled)(batch, params))
+    def _masked_call(self, key, compiled):
+        """The bucket's masked jitted entry point ``(x, valid_frames,
+        params)`` (row-parallel on a mesh), compiled once per (graph,
+        bucket)."""
         if key not in self._masked_jitted:
             if self.mesh is None:
                 self._masked_jitted[key] = compiled.masked_jit()
@@ -799,13 +843,7 @@ class SignalService:
                 def masked(x, vf, p):
                     return compiled(x, p, valid_frames=vf)
                 self._masked_jitted[key] = self.mesh.row_parallel(masked, 2)
-        # sharded batches carry zero pad rows past the wave: 0 valid
-        # frames masks every frame of a pad row (an all-zero result
-        # nothing reads back).
-        counts = [struct.valid_frames(t) for t in lens]
-        counts += [0] * (batch.shape[0] - len(counts))
-        vf = jnp.asarray(counts, jnp.int32)
-        return _to_host(self._masked_jitted[key](batch, vf, params))
+        return self._masked_jitted[key]
 
     def serve(self, requests: List[SignalRequest]) -> Dict[int, np.ndarray]:
         """Drain a request list without an LLM co-tenant."""
@@ -866,8 +904,14 @@ class SignalService:
         its carried state.  Returns the number of jitted core calls
         issued (the bench asserts <= 1 per tick per graph for
         lock-stepped sessions)."""
+        with obs.span("Streaming", "stream.tick") as sp:
+            calls = self._stream_tick()
+            if sp:
+                sp.set(core_calls=calls, sessions=self.stream_sessions())
+        return calls
+
+    def _stream_tick(self) -> int:
         calls = 0
-        _t0 = obs.now() if obs.ENABLED else 0
         # per-shard cost of THIS tick: shards run concurrently, so the
         # tick's wall-clock contribution is the max over shards.
         tick_costs: Dict[Optional[int], int] = {}
@@ -904,13 +948,20 @@ class SignalService:
                 reg = self._graphs[rep_name]
                 struct = reg.struct
                 gnames = sorted({n for n, *_ in sub})
-                _tc = obs.now() if obs.ENABLED else 0
-                stacked = jnp.stack([b for *_, b in sub])
-                if self.mesh is not None and dev is not None:
-                    stacked = jax.device_put(stacked,
-                                             self.mesh.device_for(dev))
-                res = struct.core_jit(n_frames, self.fuse, self.backend)(
-                    stacked, reg.params)
+                with obs.span("Streaming", "stream.core", graph=rep_name,
+                              graphs="+".join(gnames), n_frames=n_frames,
+                              width=len(sub),
+                              device=-1 if dev is None else dev):
+                    stacked = jnp.stack([b for *_, b in sub])
+                    if self.mesh is not None and dev is not None:
+                        stacked = jax.device_put(stacked,
+                                                 self.mesh.device_for(dev))
+                    core = struct.core_jit(n_frames, self.fuse, self.backend)
+                    with self._first_call_span(
+                            ("core", rep_name, n_frames, len(sub)),
+                            graph=rep_name, n_frames=n_frames,
+                            rows=len(sub), entry="core"):
+                        res = core(stacked, reg.params)
                 calls += 1
                 if len(gnames) > 1:
                     self.scheduler.stats["cross_graph_batches"] += 1
@@ -918,9 +969,6 @@ class SignalService:
                         obs.metrics().counter(
                             "sched.cross_graph_batches").inc()
                 if obs.ENABLED:
-                    obs.complete(f"graph/{rep_name}", "stream_core", _tc,
-                                 n_frames=n_frames, width=len(sub),
-                                 device=dev, graphs=gnames)
                     obs.metrics().histogram(
                         "service.stream_stack_width").record(len(sub))
                 cost = sum(self._stream_cost(n, n_frames)
@@ -964,10 +1012,6 @@ class SignalService:
         if calls:
             self.stats["core_calls"] += calls
         self.stats["stream_ticks"] += 1
-        if obs.ENABLED:
-            obs.complete("Streaming", "stream_tick", _t0,
-                         core_calls=calls,
-                         sessions=self.stream_sessions())
         return calls
 
     def _stream_fp(self, name: str, n_frames: int) -> Optional[Tuple]:
@@ -1102,14 +1146,11 @@ class SignalService:
             step = self._ckpt_seq
         self._ckpt_seq = step + 1
         enc, leaves = _ckpt_encode(snap)
-        t0 = obs.now() if obs.ENABLED else 0
-        Checkpointer(directory, keep=keep).save(step, leaves,
-                                                blocking=blocking,
-                                                meta=enc)
-        if obs.ENABLED:
-            obs.complete("SignalService", "save_checkpoint", t0,
-                         step=step, leaves=len(leaves),
-                         sessions=len(snap["sessions"]))
+        with obs.span("SignalService", "checkpoint", step=step,
+                      leaves=len(leaves), sessions=len(snap["sessions"])):
+            Checkpointer(directory, keep=keep).save(step, leaves,
+                                                    blocking=blocking,
+                                                    meta=enc)
         return step
 
     def restore_from_disk(self, directory: str,
@@ -1607,9 +1648,19 @@ class CoScheduler:
                             * max(1, self._wave.prefill_tokens))
 
     def tick(self) -> None:
-        _t0 = obs.now() if obs.ENABLED else 0
-        plan = self.policy.plan(self)
+        with obs.span("CoScheduler", "cosched.tick", tick=self.ticks) as sp:
+            plan = self.policy.plan(self)
+            if sp:
+                sp.set(policy=self.policy.name, run_llm=plan.run_llm,
+                       run_dsp=plan.run_dsp, run_streams=plan.run_streams,
+                       admit=plan.admit)
+            self._tick(plan)
+        self.ticks += 1
+        if obs.ENABLED:
+            self._record_tick()
 
+    def _tick(self, plan: TickPlan) -> None:
+        """One tick's work under ``plan``."""
         # LLM side (gated by the plan — a DSP-only tick must not spend
         # the array on a prefill): start a wave between waves, or admit
         # newcomers into a running wave when the policy allows it.
@@ -1648,18 +1699,10 @@ class CoScheduler:
         if plan.run_streams:
             self.signals.stream_step()
         self.dsp_cycles += self.signals.est_cycles - before
-        self.ticks += 1
-        if obs.ENABLED:
-            self._record_tick(plan, _t0)
 
-    def _record_tick(self, plan: TickPlan, t0_ns: int) -> None:
-        """One tick's trace footprint: the tick span (with the policy's
-        decisions), the DSP/LLM occupancy counter track, and per-backend
-        plan-cache hit-rate tracks."""
-        obs.complete("CoScheduler", "tick", t0_ns,
-                     tick=self.ticks, policy=self.policy.name,
-                     run_llm=plan.run_llm, run_dsp=plan.run_dsp,
-                     run_streams=plan.run_streams, admit=plan.admit)
+    def _record_tick(self) -> None:
+        """One tick's counters: the DSP/LLM occupancy counter track and
+        per-backend plan-cache hit-rate tracks."""
         occ = self.occupancy()
         tr = obs.tracer()
         tr.counter("occupancy", {"dsp_cycles": self.dsp_cycles,

@@ -75,7 +75,7 @@ import numpy as np
 from ..core import bitwidth as bw
 from ..core.exec_ir import (EinsumStep, ExecProgram, GatherStep,
                             execute_program, resolve_operand,
-                            run_steps_reference)
+                            run_steps_reference, step_kind)
 from ..core.fabric import (ShufflePlan, apply_plan, compose_into_einsum,
                            identity_plan)
 
@@ -441,8 +441,7 @@ class ReferenceBackend(ExecBackend):
         steps = stage.steps
         routes = []
         for s in steps:
-            kind = ("gather" if isinstance(s, GatherStep) else
-                    "einsum" if isinstance(s, EinsumStep) else "lambda")
+            kind = step_kind(s)
             routes.append(StepRoute(stage.name, s.name, kind,
                                     "host" if kind == "lambda" else "jnp"))
 
@@ -485,7 +484,10 @@ class PallasBackend(ExecBackend):
 
     # -- lowering -----------------------------------------------------------
     def lower_stage(self, stage):
-        units: List[Callable] = []
+        # (unit, step names): a lowered group unit takes the names of its
+        # (gather, einsum) steps at call time for its named scopes, since
+        # the cached unit is shared by steps of equal content
+        units: List[Tuple[Callable, Tuple[Optional[str], str]]] = []
         routes: List[StepRoute] = []
         steps = stage.steps
         i = 0
@@ -496,7 +498,7 @@ class PallasBackend(ExecBackend):
                 unit = self._lower_group(stage.name, nxt, gather=s)
                 if unit is not None:
                     fn, route = unit
-                    units.append(fn)
+                    units.append((fn, (s.name, nxt.name)))
                     # the group's gather runs as an XLA gather ahead of
                     # the array kernel (no kernel gathers in VMEM): the
                     # fabric pass is emulated, not fused.
@@ -509,20 +511,19 @@ class PallasBackend(ExecBackend):
                 unit = self._lower_group(stage.name, s, gather=None)
                 if unit is not None:
                     fn, route = unit
-                    units.append(fn)
+                    units.append((fn, (None, s.name)))
                     routes.append(route)
                     i += 1
                     continue
-            kind = ("gather" if isinstance(s, GatherStep) else
-                    "einsum" if isinstance(s, EinsumStep) else "lambda")
+            kind = step_kind(s)
             routes.append(StepRoute(stage.name, s.name, kind,
                                     "host" if kind == "lambda" else "jnp"))
-            units.append(_reference_unit(s))
+            units.append((_reference_unit(s), None))
             i += 1
 
         def run(x, sp):
-            for u in units:
-                x = u(x, sp)
+            for u, names in units:
+                x = u(x, sp, names)
             return x
         return run, routes
 
@@ -562,13 +563,20 @@ class PallasBackend(ExecBackend):
         from ..kernels import shuffle_gemm
         post = e.post
 
-        def unit(x, sp):
-            op = resolve_operand(e, sp)
-            w = _operand_to_canonical(op, shape, x.dtype)
+        def unit(x, sp, names):
+            gather, einsum, out = _group_scopes(names)
+            with jax.named_scope(einsum):
+                w = _operand_to_canonical(resolve_operand(e, sp), shape,
+                                          x.dtype)
             y = shuffle_gemm(x, plan, w, rows=shape.rows_total,
-                             interpret=interpret, diag=diag)
-            y = y.reshape(*y.shape[:-2], -1)
-            return apply_plan(y, post) if post is not None else y
+                             interpret=interpret, diag=diag,
+                             scopes=(gather, einsum))
+            with jax.named_scope(einsum):
+                y = y.reshape(*y.shape[:-2], -1)
+            if post is None:
+                return y
+            with jax.named_scope(out):
+                return apply_plan(y, post)
         return unit
 
     def _grouped_unit(self, e: EinsumStep, shape: _EinsumShape,
@@ -576,13 +584,19 @@ class PallasBackend(ExecBackend):
         from ..kernels import shuffle_gemm_grouped
         post = e.post
 
-        def unit(x, sp):
-            op = resolve_operand(e, sp)
-            w = _operand_to_canonical(op, shape, x.dtype)
+        def unit(x, sp, names):
+            gather, einsum, out = _group_scopes(names)
+            with jax.named_scope(einsum):
+                w = _operand_to_canonical(resolve_operand(e, sp), shape,
+                                          x.dtype)
             y = shuffle_gemm_grouped(x, plan, w, reps=shape.reps,
                                      groups=shape.groups, nb=shape.nb,
-                                     interpret=interpret, diag=diag)
-            return apply_plan(y, post) if post is not None else y
+                                     interpret=interpret, diag=diag,
+                                     scopes=(gather, einsum))
+            if post is None:
+                return y
+            with jax.named_scope(out):
+                return apply_plan(y, post)
         return unit
 
     def _int_unit(self, e: EinsumStep, shape: _EinsumShape,
@@ -629,16 +643,22 @@ class PallasBackend(ExecBackend):
         int_op = jax.custom_vjp(int_fwd)
         int_op.defvjp(st_fwd, st_bwd)
 
-        def unit(x, sp):
-            g = apply_plan(x, plan)
-            if diag is not None:
-                g = g * jnp.asarray(diag, dtype=g.dtype)
-            h = g.reshape(*g.shape[:-1], shape.rows_total, shape.t)
-            w = _operand_to_canonical(resolve_operand(e, sp), shape,
-                                      jnp.float32)
-            y = int_op(h.astype(jnp.float32), w).astype(x.dtype)
-            y = y.reshape(*y.shape[:-2], -1)
-            return apply_plan(y, post) if post is not None else y
+        def unit(x, sp, names):
+            gather, einsum, out = _group_scopes(names)
+            with jax.named_scope(gather):
+                g = apply_plan(x, plan)
+                if diag is not None:
+                    g = g * jnp.asarray(diag, dtype=g.dtype)
+            with jax.named_scope(einsum):
+                h = g.reshape(*g.shape[:-1], shape.rows_total, shape.t)
+                w = _operand_to_canonical(resolve_operand(e, sp), shape,
+                                          jnp.float32)
+                y = int_op(h.astype(jnp.float32), w).astype(x.dtype)
+                y = y.reshape(*y.shape[:-2], -1)
+            if post is None:
+                return y
+            with jax.named_scope(out):
+                return apply_plan(y, post)
         return unit
 
 
@@ -661,9 +681,19 @@ def _check_int_headroom(step_name: str, widths: Tuple[int, int],
 
 
 def _reference_unit(step):
-    def unit(x, sp):
-        return run_steps_reference([step], x, sp)
+    def unit(x, sp, names):
+        return run_steps_reference([step], x, sp)   # scopes its own step
     return unit
+
+
+def _group_scopes(names: Tuple[Optional[str], str]) -> Tuple[str, str, str]:
+    """Named scopes of one lowered group from its (gather, einsum) step
+    names: the gather feeding the array (the gather step's, else the
+    einsum's own stream-in permutation), the array pass, and the
+    einsum's stream-out permutation."""
+    gather, einsum = names
+    return (f"gather:{gather or einsum}", f"einsum:{einsum}",
+            f"gather:{einsum}")
 
 
 def _group_digest(e: EinsumStep, plan: ShufflePlan, diag,

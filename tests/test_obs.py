@@ -1,17 +1,21 @@
-"""SigTrace observability: tracer, metrics registry, report, hooks.
+"""SigTrace observability: spans, tracer, metrics registry, report.
 
-Covers the PR-6 acceptance invariants:
+Covers the acceptance invariants:
 
-  * exported Chrome Trace JSON parses, every ``B`` has a matching ``E``
-    (or spans are ``X`` complete events), timestamps are monotonic per
-    ``tid`` in record order for non-``X`` phases, counters non-negative;
+  * ``obs.span`` writes ``repro.<name>`` into a ``jax.profiler`` trace
+    while a profiler session records, an ``X`` event of the Chrome JSON
+    while ``obs.ENABLED``, both when both are on, and is the shared
+    no-op (no allocation) while neither is;
+  * exported Chrome Trace JSON parses, holds only the phases the tracer
+    writes, timestamps are monotonic per ``tid`` in record order for
+    non-``X`` phases, counters non-negative;
   * histogram p50/p95/p99 on a known distribution;
-  * disabled mode records no events and allocates nothing measurable on
-    the hook fast path;
-  * an end-to-end traced serving run contains the bucket-fill /
-    core-call / DecodeWave spans and the occupancy + plan-cache counter
+  * an end-to-end traced serving run contains the wave-phase /
+    stream / DecodeWave spans and the occupancy + plan-cache counter
     tracks, and the rendered report's percentiles match the histograms
     they came from;
+  * every stage and ``gather:``/``einsum:`` step scope reaches the
+    compiled program's op metadata;
   * ``value_and_grad`` on a non-differentiable backend is a hard error
     (no silent or warned rebind, no counter).
 """
@@ -55,31 +59,33 @@ def _graph(frame=64, hop=32):
 # --------------------------------------------------------------------------
 
 def test_trace_export_parses_and_validates(tmp_path):
-    tr = Tracer()
-    with tr.span("SignalService", "tick", {"n": 1}):
-        with tr.span("graph/fig9", "core_call"):
-            pass
-    tr.begin("DecodeWave", "prefill")
-    tr.end("DecodeWave")
-    tr.instant("SignalService", "admit", {"rid": 7})
-    tr.counter("occupancy", {"dsp_cycles": 10, "llm_cycles": 20})
+    obs.enable()
+    with obs.span("SignalService", "wave", wave=0):
+        with obs.span("SignalService", "wave.launch", wave=0) as sp:
+            sp.set(entry="masked")
+    with obs.span("DecodeWave", "engine.prefill", size=2):
+        pass
+    obs.instant("SignalService", "admit", rid=7)
+    obs.tracer().counter("occupancy", {"dsp_cycles": 10, "llm_cycles": 20})
     path = tmp_path / "trace.json"
-    tr.export(str(path))
+    obs.get_tracer().export(str(path))
 
     doc = json.loads(path.read_text())
     assert isinstance(doc["traceEvents"], list)
     stats = validate_trace(str(path))
-    assert stats["phases"]["X"] == 2
-    assert stats["phases"]["B"] == 1 and stats["phases"]["E"] == 1
+    assert stats["phases"]["X"] == 3
     assert stats["phases"]["i"] == 1 and stats["phases"]["C"] == 1
+    assert "B" not in stats["phases"] and "E" not in stats["phases"]
+    launch = [ev for ev in doc["traceEvents"] if ev["name"] == "wave.launch"]
+    assert launch[0]["args"] == {"wave": 0, "entry": "masked"}
     # lanes are named via metadata events
     names = {ev["args"]["name"] for ev in doc["traceEvents"]
              if ev["ph"] == "M" and ev["name"] == "thread_name"}
-    assert {"SignalService", "graph/fig9", "DecodeWave",
-            "counters"} <= names
+    assert {"SignalService", "DecodeWave", "counters"} <= names
 
 
 def test_validate_rejects_unbalanced_and_negative():
+    # B/E events are phases the tracer never writes (spans are X events)
     with pytest.raises(TraceError):
         validate_trace({"traceEvents": [
             {"ph": "B", "pid": 1, "tid": 1, "ts": 0.0, "name": "tick"}]})
@@ -97,6 +103,10 @@ def test_validate_rejects_unbalanced_and_negative():
         validate_trace({"traceEvents": [
             {"ph": "i", "pid": 1, "tid": 3, "ts": 9.0, "name": "a"},
             {"ph": "i", "pid": 1, "tid": 3, "ts": 4.0, "name": "b"}]})
+    with pytest.raises(TraceError):
+        validate_trace({"traceEvents": [
+            {"ph": "X", "pid": 1, "tid": 1, "ts": 0.0, "name": "w",
+             "dur": -1.0}]})
 
 
 def test_tracer_timestamps_monotonic_per_tid():
@@ -105,12 +115,6 @@ def test_tracer_timestamps_monotonic_per_tid():
         tr.instant("lane_a", f"e{i}")
         tr.counter("c", {"v": float(i)})
     assert validate_trace(tr.to_dict())["events"] == 100
-
-
-def test_end_without_begin_raises():
-    tr = Tracer()
-    with pytest.raises(TraceError):
-        tr.end("lane")
 
 
 # --------------------------------------------------------------------------
@@ -173,11 +177,16 @@ def test_disabled_mode_records_nothing():
 
 
 def test_disabled_hook_allocates_nothing():
-    # the guard pattern used at every instrumentation site
+    # the hook shapes used at the instrumentation sites: a span whose
+    # exit args are computed only for a live span, and a guarded clock
     def hook():
-        _t0 = obs.now() if obs.ENABLED else 0
-        return _t0
+        with obs.span("SignalService", "wave.stack", wave=3) as sp:
+            if sp:
+                sp.set(pad_waste=0.5)
+        return obs.now() if obs.ENABLED else 0
 
+    assert obs.span("SignalService", "wave") is obs.NO_SPAN
+    assert not obs.NO_SPAN
     hook()                           # warm up
     tracemalloc.start()
     for _ in range(1000):
@@ -185,6 +194,54 @@ def test_disabled_hook_allocates_nothing():
     _, peak = tracemalloc.get_traced_memory()
     tracemalloc.stop()
     assert peak < 4096               # no per-call allocation
+    assert obs.get_tracer().events() == []
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` inside a jax.profiler session; the ``repro.*`` host
+    events of the trace it wrote as ``(name, args)``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.")]
+
+
+def test_span_lands_in_profiler_trace_without_obs(tmp_path):
+    def work():
+        assert obs.span("SigSched", "sched.dispatch") is not obs.NO_SPAN
+        with obs.span("SignalService", "wave.h2d", wave=4, graph="g") as sp:
+            sp.set(bytes=1024)
+
+    events = _profiled(tmp_path, work)
+    assert ("repro.wave.h2d", {"wave": 4, "graph": "g", "bytes": 1024}) \
+        in events
+    assert obs.get_tracer().events() == []      # the JSON sink stayed off
+    assert obs.span("SignalService", "wave") is obs.NO_SPAN
+
+
+def test_span_feeds_both_sinks(tmp_path):
+    obs.enable()
+
+    def work():
+        with obs.span("SignalService", "wave.fetch", wave=1) as sp:
+            sp.set(bytes=8)
+
+    events = _profiled(tmp_path, work)
+    assert ("repro.wave.fetch", {"wave": 1, "bytes": 8}) in events
+    xs = [ev for ev in obs.get_tracer().events() if ev["ph"] == "X"]
+    assert [(ev["name"], ev["args"]) for ev in xs] == \
+        [("wave.fetch", {"wave": 1, "bytes": 8})]
 
 
 # --------------------------------------------------------------------------
@@ -218,15 +275,23 @@ def test_traced_serving_run_has_expected_lanes(tmp_path):
              if ev["ph"] == "X"}
     lanes = {ev["args"]["name"]: ev["tid"] for ev in doc["traceEvents"]
              if ev["ph"] == "M" and ev["name"] == "thread_name"}
-    assert (lanes["SignalService"], "bucket_fill") in names
-    assert (lanes["graph/fig9"], "core_call") in names
-    assert (lanes["Streaming"], "stream_tick") in names
+    for phase in ("wave", "wave.stack", "wave.h2d", "wave.launch",
+                  "wave.fetch", "wave.finish", "compile"):
+        assert (lanes["SignalService"], phase) in names
+    assert (lanes["SigSched"], "sched.dispatch") in names
+    assert (lanes["Streaming"], "stream.tick") in names
+    assert (lanes["Streaming"], "stream.core") in names
     assert stats["phases"]["X"] >= 4
+    waves = [ev for ev in doc["traceEvents"] if ev["name"] == "wave"]
+    assert sorted(ev["args"]["wave"] for ev in waves) == \
+        list(range(len(waves)))
 
-    # metrics side: latency histogram + plan-cache counters were fed
+    # metrics side: latency histogram, byte and plan-cache counters fed
     snap = obs.get_registry().snapshot()
     assert snap["histograms"]["service.latency_us.fig9"]["count"] == 4
     assert any(k.startswith("plan_cache.") for k in snap["counters"])
+    assert snap["counters"]["service.h2d_bytes"] >= 4 * 100 * 4
+    assert snap["counters"]["service.d2h_bytes"] > 0
 
 
 def test_traced_coscheduler_tick_counters():
@@ -258,12 +323,63 @@ def test_traced_coscheduler_tick_counters():
     assert "occupancy" in counter_names
     assert any(n.startswith("plan_cache/") for n in counter_names)
     x_names = {ev["name"] for ev in doc["traceEvents"] if ev["ph"] == "X"}
-    assert "tick" in x_names and "prefill" in x_names
-    assert "decode_step" in x_names
+    assert "cosched.tick" in x_names and "engine.prefill" in x_names
+    assert "engine.decode_step" in x_names
     validate_trace(doc)
     snap = obs.get_registry().snapshot()
     assert snap["counters"]["engine.prefills"] >= 1
     assert snap["counters"]["sched.ticks"] == sched.ticks
+
+
+# --------------------------------------------------------------------------
+# Named scopes in the compiled program
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_step_scopes_reach_compiled_hlo(backend):
+    import re
+
+    from repro.core.exec_ir import step_kind
+
+    c = _graph().compile(256, backend=backend)
+    x = jax.ShapeDtypeStruct((2, 256), jnp.float32)
+    vf = jax.ShapeDtypeStruct((2,), jnp.int32)
+    text = c.masked_jit().lower(x, vf, c.init_params()).compile().as_text()
+    scopes = {part for op in re.findall(r'op_name="([^"]*)"', text)
+              for part in op.split("/")}
+    steps = [s for st in c.program.stages for s in st.steps]
+    assert {st.name for st in c.program.stages} <= scopes
+    kinds = {step_kind(s) for s in steps}
+    assert {"gather", "einsum"} <= kinds
+    for s in steps:
+        if step_kind(s) != "lambda":
+            assert f"{step_kind(s)}:{s.name}" in scopes, s.name
+
+
+def test_pallas_group_scopes_split_gather_from_kernel():
+    """A gather paired with a shuffle_gemm call: the XLA gather runs
+    under a ``gather:`` step scope and the kernel call (interpreted on
+    the CPU, named ``shuffle_gemm``) under the einsum step's."""
+    import re
+
+    c = _graph().compile(256, backend="pallas")
+    x = jax.ShapeDtypeStruct((2, 256), jnp.float32)
+    vf = jax.ShapeDtypeStruct((2,), jnp.int32)
+    text = c.masked_jit().lower(x, vf, c.init_params()).compile().as_text()
+
+    def step_scope(op_name):         # the innermost <kind>:<step> scope
+        return [p for p in op_name.split("/")
+                if p.startswith(("gather:", "einsum:", "lambda:"))][-1]
+
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    gathers = [o for o in ops if o.endswith("_take)/gather")]
+    kernel = [o for o in ops if "/shuffle_gemm/" in o]
+    assert gathers and kernel
+    assert all(step_scope(o).startswith("gather:") for o in gathers)
+    assert all(step_scope(o).startswith("einsum:") for o in kernel)
+    # the paired gather step's own name reaches the gather it lowers to
+    first = c.program.stages[0].steps[0]
+    assert any(f"/gather:{first.name}/" in o for o in gathers)
 
 
 # --------------------------------------------------------------------------
