@@ -3,10 +3,19 @@
 A :class:`ShufflePlan` is the compiled artifact of the programmable shuffling
 fabric: a static gather-index map plus constant padding.  On the ASIC the
 plan is an instruction stream driving 16 nibble-granular shuffle units; on
-TPU the same plan is applied as an XLA gather/select immediately ahead of
-the consuming matmul — :func:`apply_plan` on the reference path, or the
-gather that writes the operand layout of the shuffle-GEMM Pallas kernels
-(kernels/shuffle_gemm).
+TPU the same plan is applied immediately ahead of the consuming matmul —
+:func:`apply_plan` on the reference path, or the pass that writes the
+operand layout of the shuffle-GEMM Pallas kernels (kernels/shuffle_gemm).
+
+On the kernel path a fabric pass has two lowerings.  A plan that is a
+*strided permutation* of its source — ``out = in.reshape(a).transpose(p)
+.reshape(b)``, as every FFT butterfly's stream-in and stream-out is —
+runs as that reshape and transpose (:func:`apply_strided`): a layout
+copy the compiler streams at memory bandwidth.  Every other plan (pad
+constants, duplicated or missing sources: framing, im2col, selections)
+runs as an indexed gather (``jnp.take`` + ``where``).
+:func:`strided_form` decides from the plan itself, on the host, when a
+backend lowers it; :func:`apply_plan`, the reference, always gathers.
 
 Equivalence of this fast path with the instruction-level semantics
 (`shuffle_ir` + `shuffle_compiler`) is a tested invariant (DESIGN.md §7.1).
@@ -15,7 +24,7 @@ Equivalence of this fast path with the instruction-level semantics
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +35,8 @@ from .shuffle_compiler import PAD, run_plan_via_isa
 __all__ = ["ShufflePlan", "PAD", "apply_plan", "apply_plan_np",
            "pad_plan_to_word", "concat_plans", "identity_plan",
            "fuse_plans", "tile_plan", "is_permutation", "is_identity",
-           "block_perm_tile", "compose_into_einsum", "adjoint_plan"]
+           "block_perm_tile", "compose_into_einsum", "adjoint_plan",
+           "strided_form", "apply_strided"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -170,6 +180,66 @@ def block_perm_tile(plan: ShufflePlan) -> Optional[int]:
         if bool((plan.gather_idx // t == pos // t).all()):
             return t
     return n  # unreachable: t == n always satisfies the check
+
+
+def strided_form(idx, n_in: int) -> Optional[Tuple[Tuple[int, ...],
+                                                   Tuple[int, ...],
+                                                   Tuple[int, ...]]]:
+    """The reshape/transpose that reproduces a gather, or ``None``.
+
+    ``idx`` is a gather index array of any shape over a source of
+    ``n_in`` elements.  When ``idx`` is a strided permutation — every
+    output position's source is ``sum(digit * stride)`` over mixed-radix
+    digits of the flat output position, and the strides tile the source
+    — returns ``(in_shape, perm, out_shape)`` such that::
+
+        src.reshape(in_shape).transpose(perm).reshape(out_shape)
+            == src[idx]
+
+    with ``out_shape == idx.shape``.  Output digits that stay adjacent in
+    the source are merged, so the transpose has the lowest rank.
+    Returns ``None`` for anything else: a PAD entry, a source read twice
+    or never (``n_in != idx.size`` included), or strides that do not
+    tile the source.  Host-side numpy, linear in ``idx.size``; the result
+    is a hashable tuple of ints (a static argument of a jitted kernel).
+    """
+    idx = np.asarray(idx)
+    flat = idx.reshape(-1).astype(np.int64)
+    n = flat.size
+    if n == 0 or n != int(n_in) or flat[0] != 0:
+        return None
+    digits = []                  # (length, stride), innermost first
+    block = 1
+    while block < n:             # each digit is at least 2 long
+        sub = flat[::block]
+        stride = int(sub[1])
+        run = sub != np.arange(sub.size, dtype=np.int64) * stride
+        length = int(np.argmax(run)) if run.any() else sub.size
+        if n % (block * length):
+            return None
+        digits.append((length, stride))
+        block *= length
+    src_axes = sorted(range(len(digits)), key=lambda k: -digits[k][1])
+    in_shape = tuple(digits[k][0] for k in src_axes)
+    perm = tuple(src_axes.index(k) for k in reversed(range(len(digits))))
+    # checked element by element: this rejects PAD, duplicated or
+    # missing sources and strides that do not tile the source
+    got = np.arange(n).reshape(in_shape).transpose(perm).reshape(-1)
+    if not np.array_equal(got, flat):
+        return None
+    return in_shape, perm, tuple(int(d) for d in idx.shape)
+
+
+def apply_strided(x: jax.Array, form) -> jax.Array:
+    """Apply a :func:`strided_form` along the last axis of ``x``
+    (leading axes are batch and stay outermost): the gather it stands
+    for, as a reshape and a transpose."""
+    in_shape, perm, out_shape = form
+    lead = x.shape[:-1]
+    k = len(lead)
+    y = x.reshape(*lead, *in_shape)
+    y = y.transpose(*range(k), *(k + p for p in perm))
+    return y.reshape(*lead, *out_shape)
 
 
 def compose_into_einsum(plan: ShufflePlan, diag,

@@ -5,9 +5,10 @@ re-tiled for the TPU memory hierarchy (HBM -> VMEM -> MXU):
 
 - bitserial_mm : variable-bitwidth integer GEMM via 4-bit plane
                  decomposition + shift-add (paper §IV / Fig 2).
-- shuffle_gemm : programmable gather/pad (an XLA gather) feeding a GEMM
-                 kernel (paper §V: the shuffling fabric feeding the
-                 array).
+- shuffle_gemm : programmable gather/pad (an XLA gather, or a layout
+                 copy where the plan is a strided permutation) feeding
+                 a GEMM kernel (paper §V: the shuffling fabric feeding
+                 the array).
 - fft_stage    : one radix-2 butterfly stage = composed shuffle plan +
                  per-twiddle-class 4x4 matmuls (paper Fig 3a).
 - fir_conv     : multi-phase FIR (im2col window gather + tap-bank GEMM,

@@ -15,6 +15,7 @@ from typing import Optional
 import jax
 
 from ...core.fabric import ShufflePlan
+from .kernel import lane_form
 from .vjp import gemm_call, grouped_call
 
 
@@ -27,7 +28,8 @@ def shuffle_gemm(x: jax.Array, plan: ShufflePlan, w: jax.Array,
                  rows: int, interpret: Optional[bool] = None,
                  diag=None, scopes=None) -> jax.Array:
     """out = reshape(apply_plan(x) (* diag), (rows, t)) @ w: an XLA
-    gather feeding one GEMM kernel.
+    fabric pass feeding one GEMM kernel — a transpose where the plan is
+    a strided permutation of ``x``, else a gather (kernel.py).
 
     x: (..., n_in); plan.n_out == rows * t; w: (t, n_out); diag is an
     optional per-element scale of the gathered stream (a GatherStep /
@@ -39,8 +41,10 @@ def shuffle_gemm(x: jax.Array, plan: ShufflePlan, w: jax.Array,
     Differentiable in ``x`` and ``w`` via a custom VJP whose backward
     pass runs on the same kernels (see shuffle_gemm/vjp.py).
     """
+    form = lane_form(plan.gather_idx.reshape(rows, -1), rows, 1, 1,
+                     x.shape[-1])
     return gemm_call(x, plan, w, rows, _resolve_interpret(interpret),
-                     diag, scopes)
+                     diag, scopes, form)
 
 
 def shuffle_gemm_grouped(x: jax.Array, plan: ShufflePlan, w: jax.Array,
@@ -59,5 +63,7 @@ def shuffle_gemm_grouped(x: jax.Array, plan: ShufflePlan, w: jax.Array,
     Differentiable in ``x`` and ``w`` via a custom VJP (vjp.py);
     ``scopes`` as in :func:`shuffle_gemm`.
     """
+    form = lane_form(plan.gather_idx.reshape(reps * groups * nb, -1),
+                     reps, groups, nb, x.shape[-1])
     return grouped_call(x, plan, w, reps, groups, nb,
-                        _resolve_interpret(interpret), diag, scopes)
+                        _resolve_interpret(interpret), diag, scopes, form)

@@ -39,7 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...core.fabric import ShufflePlan, apply_plan
-from .kernel import shuffle_gemm_blocks, shuffle_gemm_grouped_blocks
+from .kernel import (lane_form, shuffle_gemm_blocks,
+                     shuffle_gemm_grouped_blocks)
 
 # plan-cache label for adjoint (VJP) lowerings — deliberately distinct
 # from the forward backend name so plan_cache_info()["by_backend"]
@@ -59,21 +60,26 @@ def plan_blocks(plan: ShufflePlan, diag, rows: int, dtype):
 
 
 def blocks_call(xb: jax.Array, idx, pads, w: jax.Array,
-                interpret: bool, scale=None, scopes=None) -> jax.Array:
+                interpret: bool, scale=None, scopes=None,
+                form=None) -> jax.Array:
     """Run the shared-operand kernel on host-built blocks.
-    ``xb``: (B, n_in) -> (B, rows, n_out)."""
+    ``xb``: (B, n_in) -> (B, rows, n_out).  ``form``: the blocks'
+    :func:`~.kernel.lane_form`, or ``None`` to gather."""
     return shuffle_gemm_blocks(
         xb, jnp.asarray(idx), jnp.asarray(pads, dtype=xb.dtype), w,
         interpret=interpret,
-        scale=None if scale is None else jnp.asarray(scale), scopes=scopes)
+        scale=None if scale is None else jnp.asarray(scale), scopes=scopes,
+        form=form)
 
 
-def _identity_blocks(rows: int, t: int):
+def _identity_blocks(rows: int, t: int, reps: int, groups: int = 1,
+                     nb: int = 1):
     """Blocks of the identity gather over a flat (rows * t) stream —
     feeds each kernel row its own slice, used to route the cotangent
-    into the transposed GEMM."""
+    into the transposed GEMM — and their lane form (a transpose)."""
     idx = np.arange(rows * t, dtype=np.int32).reshape(rows, t)
-    return idx, np.zeros((rows, t), np.float32)
+    return (idx, np.zeros((rows, t), np.float32),
+            lane_form(idx, reps, groups, nb, rows * t))
 
 
 def _digest(plan: ShufflePlan, diag, n_in: int) -> tuple:
@@ -89,9 +95,11 @@ def _digest(plan: ShufflePlan, diag, n_in: int) -> tuple:
 
 def adjoint_lowering(plan: ShufflePlan, n_in: int, diag=None):
     """Kernel-ready blocks of the adjoint program for one forward
-    gather: ``(idx, pads, scale, ones)`` such that gathering the flat
-    cotangent through ``(idx, pads, scale)`` and contracting each row
-    against ``ones`` (an ``(m, 1)`` operand) yields ``d_x`` —
+    gather: ``(idx, pads, scale, ones, form)`` such that gathering the
+    flat cotangent through ``(idx, pads, scale)`` — as a transpose where
+    ``form`` is not ``None``: the adjoint of a strided permutation is
+    one — and contracting each row against ``ones`` (an ``(m, 1)``
+    operand) yields ``d_x`` —
     the two steps of :func:`repro.core.exec_ir.adjoint_gather_steps`
     lowered the same way the backend lowers any forward group.
 
@@ -105,7 +113,8 @@ def adjoint_lowering(plan: ShufflePlan, n_in: int, diag=None):
         m = reduce_.cin
         _, idx, pads, scale = plan_blocks(gather.plan, gather.diag,
                                           n_in, np.float32)
-        return idx, pads, scale, np.ones((m, 1), np.float32)
+        return (idx, pads, scale, np.ones((m, 1), np.float32),
+                lane_form(idx, n_in, 1, 1, plan.n_out))
 
     try:
         from ...signal import plan_cache_get
@@ -119,21 +128,23 @@ def _adjoint_dx(dg_flat: jax.Array, plan: ShufflePlan, n_in: int, diag,
                 interpret: bool) -> jax.Array:
     """Run the cached adjoint lowering on a flat cotangent:
     (B, rows * t) -> (B, n_in)."""
-    aidx, apads, ascale, ones = adjoint_lowering(plan, n_in, diag)
+    aidx, apads, ascale, ones, form = adjoint_lowering(plan, n_in, diag)
     dx = blocks_call(dg_flat, aidx, apads,
                      jnp.asarray(ones, dg_flat.dtype), interpret,
-                     scale=ascale)
+                     scale=ascale, form=form)
     return dx[..., 0]
 
 
 def gemm_call(x: jax.Array, plan: ShufflePlan, w: jax.Array, rows: int,
-              interpret: bool, diag, scopes=None) -> jax.Array:
+              interpret: bool, diag, scopes=None, form=None) -> jax.Array:
     """:func:`repro.kernels.shuffle_gemm` body with a custom VJP.
-    x: (..., n_in), w: (t, n_out) -> (..., rows, n_out)."""
+    x: (..., n_in), w: (t, n_out) -> (..., rows, n_out).  ``form``: the
+    plan's :func:`~.kernel.lane_form` (``reps = rows``), or ``None``."""
     t, idx, pads, scale = plan_blocks(plan, diag, rows, x.dtype)
 
     def impl(xb, w):
-        return blocks_call(xb, idx, pads, w, interpret, scale, scopes)
+        return blocks_call(xb, idx, pads, w, interpret, scale, scopes,
+                           form)
 
     def fwd(xb, w):
         return impl(xb, w), (xb, w)
@@ -144,9 +155,9 @@ def gemm_call(x: jax.Array, plan: ShufflePlan, w: jax.Array, rows: int,
         n_out = w.shape[-1]
         # d_gathered = dy @ w.T — the transposed GEMM via the identity
         # gather (same kernel, operand transposed)
-        iidx, ipads = _identity_blocks(rows, n_out)
+        iidx, ipads, iform = _identity_blocks(rows, n_out, rows)
         dg = blocks_call(dy.reshape(b, rows * n_out), iidx, ipads,
-                         jnp.transpose(w), interpret)
+                         jnp.transpose(w), interpret, form=iform)
         # d_x — scatter-as-gather of the inverse index map (+ diag),
         # reduced on the array
         dx = _adjoint_dx(dg.reshape(b, rows * t), plan, n_in, diag,
@@ -168,10 +179,11 @@ def gemm_call(x: jax.Array, plan: ShufflePlan, w: jax.Array, rows: int,
 
 def grouped_call(x: jax.Array, plan: ShufflePlan, w: jax.Array,
                  reps: int, groups: int, nb: int, interpret: bool,
-                 diag, scopes=None) -> jax.Array:
+                 diag, scopes=None, form=None) -> jax.Array:
     """:func:`repro.kernels.shuffle_gemm_grouped` body with a custom
     VJP.  x: (..., n_in), w: (groups, t, n_out) -> (..., R * n_out)
-    with R = reps * groups * nb."""
+    with R = reps * groups * nb.  ``form``: the plan's
+    :func:`~.kernel.lane_form`, or ``None``."""
     rows = reps * groups * nb
     t, idx, pads, scale = plan_blocks(plan, diag, rows, x.dtype)
 
@@ -180,7 +192,7 @@ def grouped_call(x: jax.Array, plan: ShufflePlan, w: jax.Array,
             xb, jnp.asarray(idx), jnp.asarray(pads, dtype=xb.dtype), w,
             reps=reps, groups=groups, nb=nb, interpret=interpret,
             scale=None if scale is None else jnp.asarray(scale),
-            scopes=scopes)
+            scopes=scopes, form=form)
 
     def fwd(xb, w):
         return impl(xb, w), (xb, w)
@@ -192,11 +204,12 @@ def grouped_call(x: jax.Array, plan: ShufflePlan, w: jax.Array,
         # d_gathered: the transposed grouped GEMM — identity gather,
         # per-group operand transposed.  Row r of the output block
         # holds dg[r, :] (length t), i.e. the plan-flat layout.
-        iidx, ipads = _identity_blocks(rows, n_out)
+        iidx, ipads, iform = _identity_blocks(rows, n_out, reps, groups,
+                                              nb)
         dg_flat = shuffle_gemm_grouped_blocks(
             dy, jnp.asarray(iidx), jnp.asarray(ipads, dy.dtype),
             jnp.transpose(w, (0, 2, 1)), reps=reps, groups=groups,
-            nb=nb, interpret=interpret)
+            nb=nb, interpret=interpret, form=iform)
         dx = _adjoint_dx(dg_flat, plan, n_in, diag, interpret)
         g = apply_plan(xb, plan)
         if scale is not None:

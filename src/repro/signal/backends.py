@@ -20,11 +20,17 @@ Two backends ship:
       - row-uniform einsums (FIR taps, DCT, mel, DWT banks) run through
         :func:`repro.kernels.shuffle_gemm` — the standalone gather ahead
         of the einsum AND the v2-folded ``pre``/``pre_diag`` stream
-        shuffle compose into ONE XLA gather that writes the kernel's
+        shuffle compose into ONE XLA pass that writes the kernel's
         operand layout (Mosaic lowers no general in-VMEM gather, so the
         fabric pass is reported as emulated);
       - grouped einsums (the FFT butterfly: per-twiddle-class matmuls)
         run through :func:`repro.kernels.shuffle_gemm_grouped`;
+      - each fabric pass of a kernel group — the stream-in and the
+        einsum's stream-out ``post`` — runs as a reshape/transpose
+        (route ``xla_transpose``) where its plan is a strided
+        permutation (:func:`repro.core.fabric.strided_form`, decided on
+        the host when the group is lowered and cached with it), else as
+        a ``jnp.take`` gather (route ``jnp``);
       - steps named by a :class:`PrecisionPolicy` are *int-routed*: the
         gathered rows and the operand are symmetrically quantized
         (:mod:`repro.core.bitwidth`) and contracted exactly on the
@@ -72,7 +78,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core import bitwidth as bw
+from ..core import fabric
 from ..core.exec_ir import (EinsumStep, ExecProgram, GatherStep,
                             execute_program, resolve_operand,
                             run_steps_reference, step_kind)
@@ -94,21 +102,30 @@ __all__ = ["ExecBackend", "ReferenceBackend", "PallasBackend",
 class StepRoute:
     """Where one lowered step executes under a backend.  ``route`` is one
     of ``fused_gemm`` / ``fused_grouped`` / ``int_bitserial`` (array
-    kernels), ``jnp`` (emulated), ``host`` (lambda glue);
+    kernels), ``jnp`` (emulated), ``xla_transpose`` (a gather step run
+    as a layout copy ahead of its kernel), ``host`` (lambda glue);
     ``absorbed_gathers`` counts standalone fabric passes a kernel
-    performs itself — none today: every kernel's gather is an XLA gather
-    ahead of it, reported as its own ``jnp`` gather route."""
+    performs itself — none today: every kernel's gather is an XLA pass
+    ahead of it, reported as its own gather route.  ``fabric`` lists
+    how each fabric pass the step runs is lowered — ``xla_transpose``
+    or ``jnp`` (a take) — stream-in first: a kernel group's passes sit
+    on its einsum's route."""
     stage: str
     step: str
     kind: str                   # 'gather' | 'einsum' | 'lambda'
     route: str
     absorbed_gathers: int = 0
+    fabric: Tuple[str, ...] = ()
 
 
 def _routes_report(name: str, routes: Sequence[StepRoute]) -> dict:
     fabric_fused = sum(r.absorbed_gathers for r in routes)
-    fabric_emulated = sum(1 for r in routes
-                          if r.kind == "gather" and r.route == "jnp")
+    fabric_emulated = sum(1 for r in routes if r.kind == "gather"
+                          and r.route in ("jnp", "xla_transpose"))
+    lowering = {"xla_transpose": 0, "jnp": 0}
+    for r in routes:
+        for how in r.fabric:
+            lowering[how] += 1
     array = [r for r in routes if r.kind == "einsum"]
     by_route: Dict[str, int] = {}
     for r in routes:
@@ -117,6 +134,7 @@ def _routes_report(name: str, routes: Sequence[StepRoute]) -> dict:
         "name": name,
         "fabric_passes": {"fused": fabric_fused,
                           "emulated": fabric_emulated},
+        "fabric_lowering": lowering,
         "array_passes": {
             "fused": sum(1 for r in array
                          if r.route in ("fused_gemm", "fused_grouped")),
@@ -499,11 +517,11 @@ class PallasBackend(ExecBackend):
                 if unit is not None:
                     fn, route = unit
                     units.append((fn, (s.name, nxt.name)))
-                    # the group's gather runs as an XLA gather ahead of
+                    # the group's gather runs as an XLA pass ahead of
                     # the array kernel (no kernel gathers in VMEM): the
                     # fabric pass is emulated, not fused.
                     routes.append(StepRoute(stage.name, s.name,
-                                            "gather", "jnp"))
+                                            "gather", route.fabric[0]))
                     routes.append(route)
                     i += 2
                     continue
@@ -517,9 +535,11 @@ class PallasBackend(ExecBackend):
                     continue
             kind = step_kind(s)
             routes.append(StepRoute(stage.name, s.name, kind,
-                                    "host" if kind == "lambda" else "jnp"))
+                                    "host" if kind == "lambda" else "jnp",
+                                    fabric=_reference_passes(s)))
             units.append((_reference_unit(s), None))
             i += 1
+        _count_fabric_passes(routes)
 
         def run(x, sp):
             for u, names in units:
@@ -543,24 +563,41 @@ class PallasBackend(ExecBackend):
 
         def build():
             if widths is not None and not shape.grouped:
-                return self._int_unit(e, shape, plan, diag, widths,
-                                      interpret), "int_bitserial"
+                return (self._int_unit(e, shape, plan, diag, widths,
+                                       interpret), "int_bitserial",
+                        ("jnp",) * (1 + (e.post is not None)))
+            from ..kernels.shuffle_gemm.kernel import lane_form
+            # the fabric passes' lowerings, decided here on the host
+            # (the plans are numpy) and cached with the group
+            form_in = lane_form(plan.gather_idx.reshape(shape.rows_total,
+                                                        -1),
+                                shape.reps, shape.groups, shape.nb,
+                                plan.n_out)
+            form_out = None if e.post is None else \
+                fabric.strided_form(e.post.gather_idx, e.post.n_out)
+            forms = (form_in,) if e.post is None else (form_in, form_out)
+            passes = tuple("jnp" if f is None else "xla_transpose"
+                           for f in forms)
             if not shape.grouped:
-                return self._gemm_unit(e, shape, plan, diag,
-                                       interpret), "fused_gemm"
-            return self._grouped_unit(e, shape, plan, diag,
-                                      interpret), "fused_grouped"
+                return self._gemm_unit(e, shape, plan, diag, interpret,
+                                       form_in, form_out), \
+                    "fused_gemm", passes
+            return self._grouped_unit(e, shape, plan, diag, interpret,
+                                      form_in, form_out), \
+                "fused_grouped", passes
 
         key = _group_digest(e, plan, diag, widths, interpret)
         from . import plan_cache_get
-        fn, route_name = plan_cache_get("exec_group", key, build,
-                                        backend=self.name)
-        return fn, StepRoute(stage_name, e.name, "einsum", route_name)
+        fn, route_name, passes = plan_cache_get("exec_group", key, build,
+                                                backend=self.name)
+        return fn, StepRoute(stage_name, e.name, "einsum", route_name,
+                             fabric=passes)
 
     # -- unit builders ------------------------------------------------------
     def _gemm_unit(self, e: EinsumStep, shape: _EinsumShape,
-                   plan: ShufflePlan, diag, interpret: bool):
-        from ..kernels import shuffle_gemm
+                   plan: ShufflePlan, diag, interpret: bool, form_in,
+                   form_out):
+        from ..kernels.shuffle_gemm.vjp import gemm_call
         post = e.post
 
         def unit(x, sp, names):
@@ -568,20 +605,20 @@ class PallasBackend(ExecBackend):
             with jax.named_scope(einsum):
                 w = _operand_to_canonical(resolve_operand(e, sp), shape,
                                           x.dtype)
-            y = shuffle_gemm(x, plan, w, rows=shape.rows_total,
-                             interpret=interpret, diag=diag,
-                             scopes=(gather, einsum))
+            y = gemm_call(x, plan, w, shape.rows_total, interpret, diag,
+                          (gather, einsum), form_in)
             with jax.named_scope(einsum):
                 y = y.reshape(*y.shape[:-2], -1)
             if post is None:
                 return y
             with jax.named_scope(out):
-                return apply_plan(y, post)
+                return _stream_out(y, post, form_out)
         return unit
 
     def _grouped_unit(self, e: EinsumStep, shape: _EinsumShape,
-                      plan: ShufflePlan, diag, interpret: bool):
-        from ..kernels import shuffle_gemm_grouped
+                      plan: ShufflePlan, diag, interpret: bool, form_in,
+                      form_out):
+        from ..kernels.shuffle_gemm.vjp import grouped_call
         post = e.post
 
         def unit(x, sp, names):
@@ -589,14 +626,13 @@ class PallasBackend(ExecBackend):
             with jax.named_scope(einsum):
                 w = _operand_to_canonical(resolve_operand(e, sp), shape,
                                           x.dtype)
-            y = shuffle_gemm_grouped(x, plan, w, reps=shape.reps,
-                                     groups=shape.groups, nb=shape.nb,
-                                     interpret=interpret, diag=diag,
-                                     scopes=(gather, einsum))
+            y = grouped_call(x, plan, w, shape.reps, shape.groups,
+                             shape.nb, interpret, diag, (gather, einsum),
+                             form_in)
             if post is None:
                 return y
             with jax.named_scope(out):
-                return apply_plan(y, post)
+                return _stream_out(y, post, form_out)
         return unit
 
     def _int_unit(self, e: EinsumStep, shape: _EinsumShape,
@@ -684,6 +720,36 @@ def _reference_unit(step):
     def unit(x, sp, names):
         return run_steps_reference([step], x, sp)   # scopes its own step
     return unit
+
+
+def _reference_passes(step) -> Tuple[str, ...]:
+    """The fabric passes a step on the reference path runs, each a
+    ``jnp.take``: a gather step's plan, an einsum's ``pre``/``post``."""
+    if isinstance(step, GatherStep):
+        return ("jnp",)
+    if isinstance(step, EinsumStep):
+        return ("jnp",) * ((step.pre is not None) + (step.post is not None))
+    return ()
+
+
+def _count_fabric_passes(routes: Sequence[StepRoute]) -> None:
+    """Count each lowered fabric pass by its lowering, at bind:
+    ``fabric.strided_passes`` (a layout copy) or ``fabric.take_passes``
+    (a gather)."""
+    m = obs.metrics()
+    for r in routes:
+        for how in r.fabric:
+            m.counter("fabric.strided_passes" if how == "xla_transpose"
+                      else "fabric.take_passes").inc()
+
+
+def _stream_out(y, post: ShufflePlan, form):
+    """An einsum's stream-out permutation: the layout copy of its
+    :func:`~repro.core.fabric.strided_form` where that spans ``y``,
+    else the gather."""
+    if form is not None and y.shape[-1] == math.prod(form[0]):
+        return fabric.apply_strided(y, form)
+    return apply_plan(y, post)
 
 
 def _group_scopes(names: Tuple[Optional[str], str]) -> Tuple[str, str, str]:

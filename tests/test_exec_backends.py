@@ -455,3 +455,114 @@ def test_interpret_default_reaches_kernels(monkeypatch):
     out = shuffle_gemm(x, identity_plan(32), w, rows=1)
     np.testing.assert_allclose(np.asarray(out)[0], np.asarray(x),
                                rtol=1e-6)
+
+
+# --------------------------------------------------------------------------
+# Strided fabric passes: layout copies, bit-identical to the gathers
+# --------------------------------------------------------------------------
+
+DCASE_LENGTH = 16_384       # 31 frames of the 1024-point, hop-512 STFT
+
+
+def _dcase_front(length):
+    """The DCASE 2020 Task 2 baseline's front end: power spectrum
+    (n_fft 1024, hop 512), 128 mels, 10 log10."""
+    g = SignalGraph("dcase_front")
+    g.stft("spec", "input", frame=1024, hop=512)
+    g.magnitude("mag", "spec", onesided=True)
+    g.mul("power", "mag", "mag")
+    g.mel_filterbank("mel", "power", sr=16_000, n_mels=128)
+    g.dnn("logmel", "mel", fn=lambda p, z: 10.0 * jnp.log10(z + 1e-12))
+    g.outputs("logmel")
+    return g
+
+
+_STRIDED_GRAPHS = {
+    "dcase": (_dcase_front, DCASE_LENGTH),
+    "fig9": (_fig9, 512),
+}
+
+
+@pytest.fixture
+def take_only(monkeypatch):
+    """Force every fabric pass onto the gather: the classifier finds no
+    strided form.  Lowerings cached either way are dropped around it."""
+    from repro.core import fabric
+    clear_plan_caches()
+    monkeypatch.setattr(fabric, "strided_form", lambda idx, n_in: None)
+    yield
+    monkeypatch.undo()
+    clear_plan_caches()
+
+
+def _run_pallas(name, batch=2):
+    build, length = _STRIDED_GRAPHS[name]
+    c = build(length).compile(length, backend="pallas")
+    return c, c(_x(length, batch=batch, seed=5))
+
+
+@pytest.mark.parametrize("name", sorted(_STRIDED_GRAPHS))
+def test_strided_passes_bit_identical_to_take(name, request):
+    """The pallas backend's outputs are the same bits whether its
+    strided fabric passes run as layout copies or as gathers."""
+    clear_plan_caches()
+    c, strided = _run_pallas(name)
+    assert c.lowering_report()["fabric_lowering"]["xla_transpose"] > 0
+    request.getfixturevalue("take_only")
+    c_take, taken = _run_pallas(name)
+    assert c_take.lowering_report()["fabric_lowering"]["xla_transpose"] \
+        == 0
+    for k in strided:
+        np.testing.assert_array_equal(np.asarray(strided[k]),
+                                      np.asarray(taken[k]))
+
+
+def test_strided_passes_counted_and_gathers_gone():
+    """The DCASE front end: s1-s9 stream-in, s9 stream-out and the mel
+    stream-in lower as layout copies, counted at bind; the framing
+    stream-in of s0 (pad constants, overlapping frames) keeps the one
+    gather of the lowered program."""
+    from repro import obs
+    clear_plan_caches()
+    m = obs.metrics()
+    before = {k: m.counter(f"fabric.{k}_passes").value
+              for k in ("strided", "take")}
+    c = _dcase_front(DCASE_LENGTH).compile(DCASE_LENGTH, backend="pallas")
+    got = {k: m.counter(f"fabric.{k}_passes").value - before[k]
+           for k in ("strided", "take")}
+    assert got == {"strided": 11, "take": 1}
+    rep = c.lowering_report()
+    assert rep["fabric_lowering"] == {"xla_transpose": 11, "jnp": 1}
+    assert [r.route for r in c._exec.routes if r.kind == "gather"] == ["jnp"]
+    x = jax.ShapeDtypeStruct((2, DCASE_LENGTH), jnp.float32)
+    text = jax.jit(c.__call__).lower(x).as_text()
+    assert text.count('"stablehlo.gather"') == 1
+
+
+def test_strided_grouped_stage_grad_matches_take(request):
+    """``jax.grad`` through one grouped FFT stage (the custom VJP's
+    identity and adjoint passes are strided too) is the same bits with
+    the passes lowered as gathers."""
+    from repro.core import fabric
+    from repro.core.fabric import tile_plan
+    from repro.core.signal_mapping import make_fft_plan
+    from repro.kernels import shuffle_gemm_grouped
+
+    n, frames = 64, 4
+    stage = make_fft_plan(n).stages[2]
+    plan = tile_plan(stage.gather, frames, 2 * n)
+    w = jnp.asarray(stage.twiddle)
+    x = _x(frames * 2 * n, batch=2, seed=9)
+
+    def loss(x, w):
+        y = shuffle_gemm_grouped(x, plan, w, reps=frames,
+                                 groups=stage.half, nb=stage.nb)
+        return jnp.sum(y * y)
+
+    assert fabric.strided_form(plan.gather_idx, plan.n_out) is not None
+    clear_plan_caches()
+    strided = jax.grad(loss, argnums=(0, 1))(x, w)
+    request.getfixturevalue("take_only")
+    taken = jax.grad(loss, argnums=(0, 1))(x, w)
+    for a, b in zip(strided, taken):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -2,11 +2,12 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core.fabric import (PAD, ShufflePlan, apply_plan, apply_plan_np,
-                               concat_plans, identity_plan,
-                               pad_plan_to_word)
+                               apply_strided, concat_plans, identity_plan,
+                               pad_plan_to_word, strided_form, tile_plan)
 
 
 def _rand_plan(rng, n_out, n_in, width=16, pad_frac=0.2):
@@ -134,3 +135,88 @@ def test_compose_into_einsum_matches_two_pass_execution():
     np.testing.assert_allclose(diag1, d1)
     plan2, diag2 = compose_into_einsum(g1, d1, None, d1)
     np.testing.assert_allclose(diag2, d1 * d1)
+
+
+# --------------------------------------------------------------------------
+# strided_form: which plans lower as a reshape/transpose
+# --------------------------------------------------------------------------
+
+FFT_FRAMES = 3
+
+
+def _fft_stage_cases():
+    from repro.core.signal_mapping import make_fft_plan
+    cases = []
+    for n in (8, 64, 1024):
+        for i, stage in enumerate(make_fft_plan(n).stages):
+            cases.append(pytest.param(n, i, "gather", id=f"fft{n}-s{i}-in"))
+            if stage.scatter.n_out:
+                cases.append(pytest.param(n, i, "scatter",
+                                          id=f"fft{n}-s{i}-out"))
+    return cases
+
+
+def _assert_form_matches_take(idx, n_in, batch=2):
+    """A strided form exists for ``idx`` and reproduces the gather
+    bit for bit, batch axes leading."""
+    form = strided_form(idx, n_in)
+    assert form is not None
+    assert form[2] == idx.shape
+    assert sorted(form[1]) == list(range(len(form[0])))
+    x = np.random.default_rng(7).standard_normal(
+        (batch, n_in)).astype(np.float32)
+    got = np.asarray(apply_strided(jnp.asarray(x), form))
+    want = np.asarray(jnp.take(jnp.asarray(x), jnp.asarray(idx), axis=-1))
+    np.testing.assert_array_equal(got, want)
+    return form
+
+
+@pytest.mark.parametrize("n,stage,which", _fft_stage_cases())
+def test_strided_form_fft_stages(n, stage, which):
+    """Every fused FFT stage's stream-in (bit reversal folded into the
+    first) and the last stage's stream-out, tiled over frames, is a
+    strided permutation."""
+    from repro.core.signal_mapping import make_fft_plan
+    plan = getattr(make_fft_plan(n).stages[stage], which)
+    tiled = tile_plan(plan, FFT_FRAMES, 2 * n)
+    form = _assert_form_matches_take(tiled.gather_idx, FFT_FRAMES * 2 * n)
+    # merged digits: no two source axes that stay adjacent in the output
+    perm = form[1]
+    assert all(perm[k + 1] != perm[k] + 1 for k in range(len(perm) - 1))
+
+
+def test_strided_form_2d_transpose():
+    idx = np.arange(6 * 5).reshape(6, 5).T
+    form = _assert_form_matches_take(idx, 30)
+    assert form[:2] == ((6, 5), (1, 0))
+    # the identity is the rank-1 form
+    assert strided_form(np.arange(12), 12) == ((12,), (0,), (12,))
+
+
+@pytest.mark.parametrize("case", ["random", "pad", "framing", "prefix"])
+def test_strided_form_rejects(case):
+    """Anything but a strided permutation of the whole source gathers."""
+    rng = np.random.default_rng(3)
+    n_in = 64
+    if case == "random":          # a permutation, but not a strided one
+        idx = rng.permutation(n_in)
+    elif case == "pad":           # pad constants (zero imaginary parts)
+        idx = np.arange(n_in)
+        idx[1::2] = PAD
+    elif case == "framing":       # overlapping frames read sources twice
+        idx = (np.arange(4)[:, None] * 8 + np.arange(16)[None, :]).ravel()
+    else:                         # a prefix of a longer source
+        idx = np.arange(48)
+    assert strided_form(idx, n_in) is None
+
+
+@settings(max_examples=40, deadline=None)
+@given(st.integers(0, 2 ** 31))
+def test_strided_form_finds_any_transpose(seed):
+    """Any reshape/transpose of a source (axes of length 1 to 5, rank 1
+    to 5) is found, and reproduces the gather."""
+    rng = np.random.default_rng(seed)
+    shape = tuple(int(d) for d in rng.integers(1, 6, rng.integers(1, 6)))
+    perm = tuple(int(p) for p in rng.permutation(len(shape)))
+    idx = np.arange(int(np.prod(shape))).reshape(shape).transpose(perm)
+    _assert_form_matches_take(idx, idx.size)
