@@ -235,6 +235,8 @@ def backlog(cell: Cell) -> Record:
     pool = generator.audio_pool(cell.mod, cell.cfg, tr, cell.seed)
     clips = generator.Backlog(tr, cell.seed, len(pool))
     depth = tr["min_waves"] * svc.batch_size
+    bucket = next(b for b in cell.cfg["service"]["buckets"]
+                  if b >= clips.length)
     for _ in range(2):
         for j in range(svc.batch_size):
             svc.submit(_request(name, -1 - j, pool[:clips.length]))
@@ -256,7 +258,7 @@ def backlog(cell: Cell) -> Record:
                 ts = now()
                 res = svc.step()
                 te = now()
-            rec.waves.append({"t0": ts, "t1": te, "bucket": clips.length,
+            rec.waves.append({"t0": ts, "t1": te, "bucket": bucket,
                               "lens": [clips.length] * len(res)})
             answered += len(res)
             if te - t0 <= cell.seconds:

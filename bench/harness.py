@@ -209,15 +209,6 @@ def run(wl: dict, spec: dict, seed: int, seconds: float, trace: bool,
     rec = drive.MODES[traffic["mode"]](cell)
     dev["memory_peak_bytes"] = memory_peak(wl["chips"])
     notes = dict(rec.notes, compile_cache=cache, window_s=rec.window_s)
-    if trace:
-        # kernel shapes of each program the window ran, from the compiled
-        # programs' text (compiled after the window, from the cache)
-        from bench import kernels
-        calls = {(w["bucket"], len(w["lens"])): None
-                 for w in rec.waves if w["lens"]}
-        for bucket, rows in calls:
-            calls[bucket, rows] = kernels.calls_in_program(
-                svc, cfg["name"], bucket, rows, params)
     del svc, cell
 
     host_params = jax.tree_util.tree_map(np.asarray, params)
@@ -238,8 +229,7 @@ def run(wl: dict, spec: dict, seed: int, seconds: float, trace: bool,
         dev["busy_s"] = summ["busy_s"]
         dev["window_s"] = summ["window_s"]
         data = RunData(cfg=cfg, mod=mod, record=rec, trace=t,
-                       peaks=peaks.for_device(dev["kind"]),
-                       kernel_calls=calls)
+                       peaks=peaks.for_device(dev["kind"]))
         for m in metrics_for(spec, wl["name"], "per_layer"):
             v = metric_reader(m["name"])(data)
             if v is not None:

@@ -60,16 +60,3 @@ def parse_calls(hlo_text: str, kernel: str) -> List[Dict[str, int]]:
         out.append(COST[kernel](res[0], ops))
     return out
 
-
-def calls_in_program(svc, name: str, bucket: int, rows: int, params,
-                     kernel: str = "shuffle_gemm") -> List[Dict[str, int]]:
-    """Kernel calls of the service's masked bucket program at ``rows``
-    rows."""
-    import jax
-    import jax.numpy as jnp
-
-    compiled = svc.compiled_for(name, bucket)
-    x = jax.ShapeDtypeStruct((rows, bucket), jnp.float32)
-    vf = jax.ShapeDtypeStruct((rows,), jnp.int32)
-    text = compiled.masked_jit().lower(x, vf, params).compile().as_text()
-    return parse_calls(text, kernel)

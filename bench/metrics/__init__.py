@@ -7,7 +7,6 @@ the metric out of the result line).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Tuple
 
 from bench import trace as tr
 
@@ -19,8 +18,6 @@ class RunData:
     record: object              # drive.Record of the run
     trace: tr.Trace
     peaks: dict                 # bench.peaks entry of the device
-    # (bucket, rows) -> kernel calls of that program, bench.kernels costs
-    kernel_calls: Dict[Tuple[int, int], List[Dict[str, int]]]
 
 
 def host_ms_per_span(run: RunData, names, per: str):

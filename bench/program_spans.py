@@ -153,10 +153,11 @@ def program_ops(trace: tr.Trace, prog: Program, hlo: HloText,
     return out
 
 
-def program_texts(run) -> List[str]:
+def program_texts(run) -> Dict[Tuple[int, int], str]:
     """Optimised HLO text of each (bucket, rows) program the run's window
     executed: a service built like the harness's, its masked bucket
-    program compiled after the window (from the compile cache)."""
+    program compiled after the window (from the compile cache).  Only
+    the readers that need a program's text call this."""
     import jax
     import jax.numpy as jnp
     from repro.serving import SignalService
@@ -165,14 +166,14 @@ def program_texts(run) -> List[str]:
     svc = SignalService(**cfg["service"])
     svc.register(cfg["name"], mod.build_graph(cfg))
     params = jax.eval_shape(lambda: mod.make_params(cfg, 0))
-    texts = []
+    texts = {}
     for bucket, rows in sorted({(w["bucket"], len(w["lens"]))
                                 for w in run.record.waves if w["lens"]}):
         compiled = svc.compiled_for(cfg["name"], bucket)
         x = jax.ShapeDtypeStruct((rows, bucket), jnp.float32)
         vf = jax.ShapeDtypeStruct((rows,), jnp.int32)
-        texts.append(compiled.masked_jit().lower(x, vf, params)
-                     .compile().as_text())
+        texts[bucket, rows] = (compiled.masked_jit().lower(x, vf, params)
+                               .compile().as_text())
     return texts
 
 
@@ -244,7 +245,7 @@ def scoped_device_ms_per_wave(run, texts: Optional[List[str]] = None,
     t0, t1 = tr.window(run.trace)
     prog = load(_trace_dir())
     total = 0.0
-    for text in texts if texts is not None else program_texts(run):
+    for text in texts if texts is not None else program_texts(run).values():
         for e, op_name in program_ops(run.trace, prog, parse_hlo(text),
                                       t0, t1):
             if op_name and under_scope(op_name, prefix):

@@ -66,3 +66,46 @@ def test_stream_sessions_are_staggered_evenly(fig9):
     assert all(len(x.audio) == need for x in s)
     again = generator.streams(mod, cfg, tr, 3, 1.0)
     assert all(np.array_equal(x.audio, y.audio) for x, y in zip(s, again))
+
+
+class _Service:
+    """Answers every queued request at once, up to ``batch_size`` a
+    wave, and records each wave's rows and lengths."""
+
+    def __init__(self, batch_size):
+        self.batch_size = batch_size
+        self.queue = []
+        self.waves = []
+
+    def submit(self, req):
+        self.queue.append(req)
+
+    def pending(self):
+        return len(self.queue)
+
+    def step(self):
+        wave, self.queue = (self.queue[:self.batch_size],
+                            self.queue[self.batch_size:])
+        self.waves.append([(r.rid, len(r.samples)) for r in wave])
+        return {r.rid: {} for r in wave}
+
+
+def test_fixed_length_open_loop_warms_only_its_bucket():
+    from bench import drive
+    cfg, mod = harness.load_config("dcase2020-t2-ae")
+    cfg = dict(cfg, service={"buckets": [4096, 8192, 16384]})
+    tr = {"mode": "open_loop", "rate_per_s": 10, "pool_seconds": 2,
+          "check_sample": 2,
+          "length": {"dist": "fixed", "value": 8192, "min": 8192,
+                     "max": 8192}}
+    svc = _Service(batch_size=3)
+    cell = drive.Cell(svc, cfg["name"], cfg, mod, tr, 2 ** 31 + 9, 0.3,
+                      0.0, False, lambda: None, lambda: None,
+                      drive.CompileCounter())
+    rec = drive.open_loop(cell)
+    warm = [w for w in svc.waves if w and w[0][0] < 0]
+    # one wave of each row count, every row at the one bucket's length
+    assert [len(w) for w in warm] == [1, 2, 3]
+    assert {n for w in warm for _, n in w} == {8192}
+    assert rec.attempted == 3 and rec.failed == 0
+    assert all(w["bucket"] == 8192 for w in rec.waves if w["lens"])

@@ -87,8 +87,9 @@ TINY = {
     "dcase2020-t2-ae": (
         {"service": {"batch_size": 2, "backend": "reference",
                      "buckets": [8192]}},
-        {"length": {"dist": "fixed", "value": 8192}, "pool_seconds": 2,
-         "check_sample": 4}),
+        {"rate_per_s": 10, "pool_seconds": 2, "check_sample": 4,
+         "length": {"dist": "fixed", "value": 8192, "min": 8192,
+                    "max": 8192}}),
     "fig9-speech-enhance": (
         {"service": {"batch_size": 2, "backend": "reference",
                      "buckets": [4096], "block_frames": 8}},
@@ -101,6 +102,7 @@ TINY = {
 
 # every traffic mode, including the Fig-9 mixes that no cell runs yet
 CELLS = {"dcase-offline": ("dcase2020-t2-ae", "dcase-backlog"),
+         "dcase-oneshot": ("dcase2020-t2-ae", "dcase-poisson"),
          "fig9-stream": ("fig9-speech-enhance", "fig9-stream-sessions"),
          "fig9-oneshot": ("fig9-speech-enhance", "fig9-oneshot-poisson")}
 
@@ -125,6 +127,36 @@ def test_sound_offline_and_stream_runs_are_correct():
         assert result["attempted"] > 0 and result["failed"] == 0
         assert set(result["metrics"]) >= {"setup_s"}
         assert list(result)[-1] == "checks"
+
+
+def test_sound_oneshot_run_is_correct_and_times_each_request():
+    result, checks = _run("dcase-oneshot")
+    assert result["correct"], checks
+    assert result["attempted"] == 10 and result["failed"] == 0
+    m = result["metrics"]
+    assert set(m) == {"setup_s", "latency_p50_ms", "latency_p95_ms"}
+    assert 0 < m["latency_p50_ms"]["value"] <= m["latency_p95_ms"]["value"]
+
+
+def test_backlog_waves_record_the_bucket_they_ran_in():
+    """Clips shorter than their bucket: each wave is recorded at the
+    pinned bucket, the program it ran, so the padding shows."""
+    import time
+    from bench import drive
+    wl = {"name": "padded", "config": "dcase2020-t2-ae",
+          "traffic": "dcase-backlog", "chips": 1}
+    cfg_over, tr_over = TINY["dcase2020-t2-ae"]
+    tr_over = dict(tr_over, length={"dist": "fixed", "value": 6000})
+    cfg, mod, traffic, _, svc = harness.build(wl, 3, cfg_over, tr_over)
+    cell = drive.Cell(svc, cfg["name"], cfg, mod, traffic, 3, 0.5,
+                      time.perf_counter(), False, lambda: None,
+                      lambda: None, drive.CompileCounter())
+    rec = drive.backlog(cell)
+    assert rec.waves and all(w["bucket"] == 8192 and w["lens"] == [6000] * 2
+                             for w in rec.waves)
+    pad = harness.metric_reader("pad_waste")(
+        type("Run", (), {"record": rec})())
+    assert pad == pytest.approx(100.0 * (8192 - 6000) / 8192)
 
 
 def _alter(monkeypatch):
@@ -170,6 +202,7 @@ def _alter_stream(monkeypatch):
 
 @pytest.mark.parametrize("cell,fault", [
     ("dcase-offline", _alter), ("dcase-offline", _drop_half),
+    ("dcase-oneshot", _alter), ("dcase-oneshot", _drop_half),
     ("fig9-oneshot", _alter), ("fig9-oneshot", _drop_half),
     ("fig9-stream", _alter_stream)])
 def test_broken_timed_path_is_not_correct(monkeypatch, cell, fault):

@@ -51,8 +51,7 @@ def test_gap_labels_and_summary_on_hand_made_events():
     # gaps: [0,10) step, [40,70) mostly wait, [80,100) step
     assert idle["bench.wait"] == pytest.approx(30e-9)
     assert idle["bench.step"] == pytest.approx(30e-9)
-    run = RunData(cfg={}, mod=None, record=None, trace=trace, peaks={},
-                  kernel_calls={})
+    run = RunData(cfg={}, mod=None, record=None, trace=trace, peaks={})
     # step spans: 40 + 30 ns long, 30 + 10 ns of device time inside
     assert host_ms_per_span(run, {"bench.step"}, "bench.step") \
         == pytest.approx((70 - 40) / 2 / 1e6)

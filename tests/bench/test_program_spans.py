@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import harness, program_spans as ps
+from bench import harness, peaks, program_spans as ps
 from bench import trace as tr
 from bench.metrics import RunData, host_ms_per_span
 
@@ -147,8 +147,7 @@ def _recorded(monkeypatch, name):
     steps = trace.spans_named("bench.step")
     waves = [{"bucket": 160000, "lens": [160000] * 16} for _ in steps]
     rec = type("Record", (), {"waves": waves})()
-    return RunData(cfg={}, mod=None, record=rec, trace=trace, peaks={},
-                   kernel_calls={})
+    return RunData(cfg={}, mod=None, record=rec, trace=trace, peaks={})
 
 
 @pytest.fixture
@@ -156,7 +155,8 @@ def dcase(monkeypatch):
     run = _recorded(monkeypatch, "dcase_spans.xplane.pb")
     with open(os.path.join(TESTDATA, "dcase_spans.hlo.txt")) as f:
         text = f.read()
-    monkeypatch.setattr(ps, "program_texts", lambda run: [text])
+    monkeypatch.setattr(ps, "program_texts",
+                        lambda run: {(160000, 16): text})
     return run, text
 
 
@@ -195,6 +195,16 @@ def test_recorded_ops_map_to_hlo_and_gathers_count_exactly(dcase):
     assert ps.scoped_device_ms_per_wave(run) == \
         pytest.approx(gather / waves / 1e6)
     assert 0 < gather < total
+
+
+def test_roofline_takes_kernel_calls_from_the_program_text(dcase):
+    """The recorded waves' 11 ``shuffle_gemm`` calls each, costed from
+    the shapes in the HLO text, over their device time: the share the
+    chip run that recorded the trace read."""
+    run, _ = dcase
+    run.peaks = peaks.for_device("TPU v5 lite")
+    v = harness.metric_reader("shuffle_gemm_roofline.offline")(run)
+    assert v == pytest.approx(23.088, abs=1e-3)
 
 
 def test_readers_find_nothing_in_a_trace_without_program_spans(monkeypatch):
